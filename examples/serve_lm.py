@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.checkpoint import load_checkpoint
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 from repro.serve import DecodeEngine, greedy_sample, temperature_sample
 
@@ -23,6 +24,7 @@ from train_lm import model_100m  # noqa: E402 (same directory)
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--ckpt", default="runs/train_lm_ckpt.npz")
     ap.add_argument("--batch", type=int, default=4)

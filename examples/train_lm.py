@@ -15,6 +15,7 @@ import jax
 
 from repro.data import SyntheticLMData
 from repro.checkpoint import save_checkpoint
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.config import ModelConfig, uniform_pattern
 from repro.optim import make_optimizer
 from repro.optim.schedules import cosine_warmup
@@ -31,6 +32,7 @@ def model_100m(layers=8, d_model=768):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--workers", type=int, default=4)

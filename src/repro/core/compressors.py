@@ -348,7 +348,9 @@ class NaturalCompression(UnbiasedCompressor):
     def __call__(self, key, x):
         ax = jnp.abs(x)
         lo_exp = jnp.floor(jnp.log2(jnp.where(ax > 0, ax, 1.0)))
-        lo = jnp.exp2(lo_exp)
+        # ldexp sets the exponent directly, so every power of two is exact
+        # (exp2 is off by an ulp for many |exponents| >= 13 under XLA)
+        lo = jnp.ldexp(jnp.ones_like(ax), lo_exp.astype(jnp.int32))
         hi = lo * 2.0
         # p(hi) chosen so expectation is exact: ax = p*hi + (1-p)*lo
         p_hi = jnp.where(ax > 0, (ax - lo) / (hi - lo), 0.0)

@@ -25,27 +25,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-
-if hasattr(jax, "shard_map"):  # jax >= 0.6
-    from jax import shard_map as _shard_map
-else:  # jax 0.4.x: experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """Version-portable shard_map: old jax calls it ``check_rep``, very old
-    jax supports neither kwarg — fall back by dropping it."""
-    try:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                          check_vma=check_vma)
-    except TypeError:
-        pass
-    try:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                          check_rep=check_vma)
-    except TypeError:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
 
 from .compressors import RandK, TopK
 from .problems import paper_sign
@@ -161,7 +141,7 @@ def make_marina_p_spmd_step(
         }
         return x_new, W_new, t + 1, metrics
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         round_fn,
         mesh=mesh,
         in_specs=(P(), P(axis), P(), P(axis), P()),
@@ -206,7 +186,7 @@ def make_ef21p_spmd_step(
                    "delta_nnz": jnp.sum(delta != 0).astype(jnp.float32)}
         return x_new, w_new, t + 1, metrics
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         round_fn,
         mesh=mesh,
         in_specs=(P(), P(), P(), P(axis)),

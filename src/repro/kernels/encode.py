@@ -1,36 +1,38 @@
-"""Fused on-device compressor -> bitstream encode kernels (Pallas TPU).
+"""On-device compressor -> bitstream encode pipelines (Pallas TPU).
 
 The host codecs (repro/wire) top out around ~0.5 GB/s, which makes encoding
 the N per-worker compressed broadcasts of a MARINA-P round the downlink
 bottleneck at scale (ROADMAP "on-device encode path and codec speed").
-The kernels here fuse compressor selection and stream extraction into one
-VMEM pass and bit-pack with the word-aligned compare-and-sum layout of
-``kernels/pack.py``, so the packed uint32 words leave the device
-send-ready; the host contributes only the 16 fixed header/payload bytes.
+The pipelines here run compressor selection, stream extraction and
+bit-packing (``kernels/pack.py``) on the device, so the packed uint32 words
+leave it send-ready; the host contributes only the 16 fixed header/payload
+bytes.
 
-Fused paths — each **byte-identical** to the host codec on every input
+Paths — each **byte-identical** to the host codec on every input
 (asserted by the differential harness in tests/test_encode_diff.py):
 
-* :func:`topk_encode`  — block-TopK select -> (index, sign, magnitude)
-  streams -> packed words, ``== wire.encode_sparse(ops.block_topk(x))``.
-  Selection reuses kernels/topk.py's iterative-extraction semantics
-  (first-index tie-break, bit-identical to ``jax.lax.top_k``).
-* :func:`mask_encode`  — BernK counter-hash mask + scale + streams, seeded
-  on-device with ``kernels/randk.hash_uniform`` so the mask bit-matches the
-  SEED codec's receiver-side rematerialization (wire/seedonly.py, BERN
-  family with ``seed + round`` folded by the caller).
+* :func:`topk_encode`  — one fused kernel selects each block's top-k and
+  compacts it into (index, value) slots in index order, then the streams
+  are packed: ``== wire.encode_sparse(ops.block_topk(x))``. Selection is
+  kernels/topk.py's iterative extraction (first-index tie-break, the
+  semantics of ``jax.lax.top_k``).
+* :func:`mask_encode`  — the BernK kernel of kernels/randk.py (counter-hash
+  mask + scale, seeded on device) feeds the streams, so the mask
+  bit-matches the SEED codec's receiver-side rematerialization
+  (wire/seedonly.py, BERN family with ``seed + round`` folded by the
+  caller).
 * :func:`sparse_encode` — streams for an arbitrary already-sparsified
   vector (the ``measure_wire`` call sites hold Q on device already).
 * :func:`dense_encode` — DENSE codec payload for full-sync rounds.
-* :func:`encode_rows` / :func:`encode_per_worker` — batched N-stream paths
-  (vmap over message rows / the on-device worker id) amortizing the
-  per-round fan-out of MARINA-style per-worker messages.
+* :func:`encode_rows` — the per-worker messages of a MARINA-style round,
+  one row at a time; :func:`encode_per_worker` — N BernK streams of one
+  shared vector in one device program (a ``lax.map`` over worker ids).
 
 Dynamic sizing: the SPARSE layout is compacted by nonzero count, so one
 scalar per message is read back to trim the word streams; everything else
 stays on device with static shapes. Compaction is a stable argsort on the
 validity mask (kept entries first, ascending index — exactly
-``np.nonzero`` order), which batches under ``jax.vmap`` unchanged.
+``np.nonzero`` order).
 
 ``device_encode_enabled`` is the routing policy for the integration points
 (wire/registry.py, core runs, train/downlink.py, fleet/cohort.py):
@@ -60,8 +62,9 @@ from repro.wire.spec import (
 )
 
 from . import pack as _pack
-from .randk import hash_uniform
-from .runtime import resolve_interpret
+from .randk import bernk_compress
+from .runtime import resolve_interpret, row_tiling
+from .topk import select_topk
 
 # Payload layouts mirror wire/sparse.py (the single source of the byte
 # format is DESIGN.md §3.1/§3.4; these structs must match _PAYLOAD there).
@@ -91,8 +94,55 @@ def device_encode_enabled(override: bool | None = None) -> bool:
 
 
 # ---------------------------------------------------------------------------
-# kernel bodies
+# fused top-k kernel
 # ---------------------------------------------------------------------------
+
+_ROW_TILE = 1 << 14  # f32 elements per top-k grid step (see kernels/topk.py)
+
+
+def _topk_streams_kernel(x_ref, idx_ref, bits_ref, *, k: int, block: int):
+    """Fused block-TopK: select + compact in one VMEM pass.
+
+    Each row of the tile is one compression block. Selection is the exact
+    iterative extraction of kernels/topk.py (k rounds of masked argmax,
+    first-index tie-break). The selected entries are then drawn out in
+    ascending index order, one slot per round (the smallest index still
+    kept, and its f32 bit pattern by an integer compare-and-sum, exact for
+    every payload: denormals would not survive a float sum under FTZ), so
+    the concatenated per-row slots are already in global np.nonzero order.
+    """
+    i = pl.program_id(0)
+    x = x_ref[...]  # [R, b] f32
+    rows, b = x.shape
+    ks = idx_ref.shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (rows, ks), 1)
+    xbits = jax.lax.bitcast_convert_type(x, jnp.int32)
+
+    def draw(s, carry):
+        left, local, vbits = carry
+        first = jnp.min(jnp.where(left != 0, lane, b), axis=-1, keepdims=True)
+        hit = lane == first
+        val = jnp.sum(jnp.where(hit, xbits, 0), axis=-1, keepdims=True)
+        put = slot == s
+        return (jnp.where(hit, 0, left), jnp.where(put, first, local),
+                jnp.where(put, val, vbits))
+
+    empty = jnp.zeros((rows, ks), jnp.int32)
+    _, local, vbits = jax.lax.fori_loop(
+        0, ks, draw, (select_topk(jnp.abs(x), k), empty, empty))
+    row = i * rows + jax.lax.broadcasted_iota(jnp.int32, (rows, ks), 0)
+    idx_ref[...] = (row * block + local).astype(jnp.uint32)
+    bits_ref[...] = jax.lax.bitcast_convert_type(vbits, jnp.uint32)
+
+
+# ---------------------------------------------------------------------------
+# device pipelines (jitted, static shapes; the count is a traced scalar)
+# ---------------------------------------------------------------------------
+
+
+def _pad_to(x, mult):
+    return jnp.pad(x, (0, (-x.shape[-1]) % mult))
 
 
 def _valbits(v, m: MagDType):
@@ -107,173 +157,47 @@ def _valbits(v, m: MagDType):
     return jax.lax.bitcast_convert_type(v.astype(fdt), jnp.uint16).astype(jnp.uint32)
 
 
-def _emit_stream_bits(bits, sign_ref, mag_ref, valid_ref, m: MagDType):
-    """Shared SPARSE-stream epilogue over f32 *bit patterns*.
+def _pack_sparse(bits, idx, *, iw: int, m: MagDType, interpret: bool):
+    """SPARSE streams of the f32 bit patterns ``bits`` ([n] u32): the
+    entries with nonzero magnitude bits, in order, with their indices
+    ``idx`` (None: the position in ``bits``).
 
     Works on bits, not floats, because the host codec's primitives are all
     bitwise (np.signbit = bit 31, np.abs = clear bit 31, np.nonzero =
     magnitude bits != 0) while XLA CPU flushes denormals to zero in float
-    arithmetic/compares — a ``val != 0`` here would silently elide a
-    denormal payload the host codec keeps. NaN/inf/-0.0 fall out exactly:
-    -0.0 has zero magnitude bits (elided like the host), NaN magnitude
-    bits are nonzero (kept like the host).
+    compares — a ``val != 0`` here would silently elide a denormal payload
+    the host codec keeps. NaN/inf/-0.0 fall out exactly: -0.0 has zero
+    magnitude bits (elided like the host), NaN magnitude bits are nonzero
+    (kept like the host).
+
+    Compaction is a stable argsort on the validity mask (kept entries
+    first, ascending index — exactly ``np.nonzero`` order). Everything
+    behind the count is zeroed, so packing the full-length streams leaves
+    only zero bits past ``count * width``: the host codec's padding.
     """
-    sign_ref[...] = bits >> jnp.uint32(31)
     magbits = bits & jnp.uint32(0x7FFFFFFF)
-    if m == MagDType.FP32:
-        mag_ref[...] = magbits
-    else:
-        mag_ref[...] = _valbits(
-            jax.lax.bitcast_convert_type(magbits, jnp.float32), m
-        )
-    valid_ref[...] = (magbits != 0).astype(jnp.uint32)
-
-
-def _emit_streams(val, sign_ref, mag_ref, valid_ref, m: MagDType):
-    bits = jax.lax.bitcast_convert_type(val, jnp.uint32)
-    _emit_stream_bits(bits, sign_ref, mag_ref, valid_ref, m)
-
-
-def _sparse_streams_kernel(x_ref, sign_ref, mag_ref, valid_ref, *, m: MagDType):
-    """Streamify an arbitrary (already sparsified) vector block."""
-    _emit_streams(x_ref[...], sign_ref, mag_ref, valid_ref, m)
-
-
-def _mask_streams_kernel(x_ref, w_ref, sign_ref, mag_ref, valid_ref, *,
-                         keep_prob: float, seed: int, block: int, m: MagDType):
-    """Fused BernK: counter-hash mask + scale + streamify in one pass.
-
-    The hash is kernels/randk.hash_uniform on the *global* index, so the
-    mask is bit-identical to ops.bernk and to the SEED codec's
-    receiver-side rematerialization. ``worker`` is a runtime operand (not
-    a closure static) so the per-worker fan-out batches under vmap.
-    """
-    i = pl.program_id(0)
-    x = x_ref[...]  # [1, b]
-    worker = w_ref[0]
-    local = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-    gidx = (i * block + local).astype(jnp.uint32)
-    u = hash_uniform(gidx, seed, worker)
-    val = jnp.where(u < keep_prob, x / keep_prob, 0.0)
-    _emit_streams(val, sign_ref, mag_ref, valid_ref, m)
-
-
-def _topk_streams_kernel(x_ref, idx_ref, sign_ref, mag_ref, valid_ref, *,
-                         k: int, block: int, m: MagDType):
-    """Fused block-TopK: select + compact + streamify in one VMEM pass.
-
-    Selection is the exact iterative extraction of kernels/topk.py (k
-    rounds of masked argmax, first-index tie-break). The selected entries
-    are then compacted into the leading ``k`` output slots in ascending
-    index order — a rank (cumsum of the keep mask) equality against a
-    broadcast slot iota, the same scatter-free compare-and-sum idiom as
-    kernels/pack.py — so the concatenated per-block streams are already in
-    global np.nonzero order.
-    """
-    i = pl.program_id(0)
-    x = x_ref[...]  # [1, b]
-    b = x.shape[-1]
-    absx = jnp.abs(x)
-    idx = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-
-    def body(_, carry):
-        remaining, keep = carry
-        mx = jnp.max(remaining)
-        is_max = remaining == mx
-        first = jnp.min(jnp.where(is_max, idx, b))
-        sel = idx == first
-        return remaining * (1.0 - sel) - sel, keep | sel
-
-    keep0 = jnp.zeros(x.shape, dtype=jnp.bool_)
-    _, keep = jax.lax.fori_loop(0, k, body, (absx.astype(jnp.float32), keep0))
-
-    ks = min(k, b)  # slots: never more than the block holds
-    rank = jnp.cumsum(keep.astype(jnp.int32), axis=-1) - 1          # [1, b]
-    slot = jax.lax.broadcasted_iota(jnp.int32, (1, ks, b), 1)
-    hit = keep[:, None, :] & (rank[:, None, :] == slot)             # [1, ks, b]
-    # gather via integer compare-and-sum on the f32 bit patterns: exact for
-    # every payload (denormals would not survive a float-sum under FTZ)
-    xbits = jax.lax.bitcast_convert_type(x, jnp.uint32)
-    valbits = jnp.sum(jnp.where(hit, xbits[:, None, :], jnp.uint32(0)), axis=2)
-    gidx = (i * block + idx).astype(jnp.uint32)
-    idx_ref[...] = jnp.sum(jnp.where(hit, gidx[:, None, :], jnp.uint32(0)), axis=2)
-    _emit_stream_bits(valbits, sign_ref, mag_ref, valid_ref, m)
-
-
-def _dense_bits_kernel(x_ref, out_ref, *, m: MagDType):
-    """DENSE codec pass: raw value -> wire-dtype bit pattern (sign kept)."""
-    out_ref[...] = _valbits(x_ref[...], m)
-
-
-# ---------------------------------------------------------------------------
-# device pipelines (jitted, static shapes; the count is a traced scalar)
-# ---------------------------------------------------------------------------
-
-
-def _pad_to(x, mult):
-    d = x.shape[-1]
-    pad = (-d) % mult
-    return (jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)]), d) if pad else (x, d)
-
-
-def _block_spec(block):
-    return pl.BlockSpec((1, block), lambda i: (i, 0))
-
-
-def _pack_stream(vals, *, width: int, interpret: bool):
-    """Word-pack a full-length stream on device; returns every word the
-    stream could need (callers trim to ``n_words(count, width)``)."""
-    n = vals.shape[-1]
-    if n == 0:
-        return jnp.zeros((0,), jnp.uint32)
-    vpb, _ = _pack.word_block(width)
-    pad = (-n) % vpb
-    vp = jnp.pad(vals, (0, pad)) if pad else vals
-    nwords = -(-n * width // 32)
-    return _pack.pack_bits_device(vp, width=width, interpret=interpret)[:nwords]
-
-
-def _compact_streams(idx, sign, mag, valid):
-    """Move valid entries to the front in ascending-index order and zero
-    everything behind the count (so packing the full-length stream leaves
-    only zero bits past ``count * width`` — the host codec's padding)."""
-    order = jnp.argsort(jnp.logical_not(valid.astype(bool)), axis=-1, stable=True)
-    take = functools.partial(jnp.take_along_axis, indices=order, axis=-1)
-    idx, sign, mag = take(idx), take(sign), take(mag)
-    count = jnp.sum(valid, axis=-1).astype(jnp.uint32)
-    live = (
-        jax.lax.broadcasted_iota(jnp.uint32, idx.shape, idx.ndim - 1)
-        < count[..., None]
-    ).astype(jnp.uint32)
-    return idx * live, sign * live, mag * live, count
-
-
-def _pack_sparse(idx, sign, mag, valid, *, iw: int, m: MagDType, interpret: bool):
-    idx, sign, mag, count = _compact_streams(idx, sign, mag, valid)
+    valid = magbits != 0
+    order = jnp.argsort(jnp.logical_not(valid), stable=True)
+    count = jnp.sum(valid, dtype=jnp.uint32)
+    live = jnp.arange(bits.shape[-1], dtype=jnp.uint32) < count
+    idx = order.astype(jnp.uint32) if idx is None else idx[order]
+    vb = bits[order]
+    mag = _valbits(jax.lax.bitcast_convert_type(vb & jnp.uint32(0x7FFFFFFF),
+                                                jnp.float32), m)
+    zero = jnp.uint32(0)
+    pack = functools.partial(_pack.pack_bits_device, interpret=interpret)
     return (
         count,
-        _pack_stream(idx, width=iw, interpret=interpret),
-        _pack_stream(sign, width=1, interpret=interpret),
-        _pack_stream(mag, width=MAG_BITS[m], interpret=interpret),
+        pack(jnp.where(live, idx, zero), width=iw),
+        pack(jnp.where(live, vb >> jnp.uint32(31), zero), width=1),
+        pack(jnp.where(live, mag, zero), width=MAG_BITS[m]),
     )
 
 
-@functools.partial(jax.jit, static_argnames=("m", "block", "iw", "interpret"))
-def _sparse_device(x, *, m: MagDType, block: int, iw: int, interpret: bool):
-    xp, d = _pad_to(x.astype(jnp.float32), block)
-    nblocks = xp.shape[-1] // block
-    sign, mag, valid = pl.pallas_call(
-        functools.partial(_sparse_streams_kernel, m=m),
-        grid=(nblocks,),
-        in_specs=[_block_spec(block)],
-        out_specs=[_block_spec(block)] * 3,
-        out_shape=[jax.ShapeDtypeStruct((nblocks, block), jnp.uint32)] * 3,
-        interpret=interpret,
-    )(xp.reshape(nblocks, block))
-    idx = jnp.arange(nblocks * block, dtype=jnp.uint32)
-    flat = lambda a: a.reshape(-1)
-    return _pack_sparse(idx, flat(sign), flat(mag), flat(valid),
-                        iw=iw, m=m, interpret=interpret)
+@functools.partial(jax.jit, static_argnames=("m", "iw", "interpret"))
+def _sparse_device(x, *, m: MagDType, iw: int, interpret: bool):
+    bits = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
+    return _pack_sparse(bits, None, iw=iw, m=m, interpret=interpret)
 
 
 @functools.partial(
@@ -281,22 +205,13 @@ def _sparse_device(x, *, m: MagDType, block: int, iw: int, interpret: bool):
 )
 def _mask_device(x, worker, *, keep_prob: float, seed: int, m: MagDType,
                  block: int, iw: int, interpret: bool):
-    """``worker`` is a [1] int32 operand — vmap it for the per-worker path."""
-    xp, d = _pad_to(x.astype(jnp.float32), block)
-    nblocks = xp.shape[-1] // block
-    sign, mag, valid = pl.pallas_call(
-        functools.partial(_mask_streams_kernel, keep_prob=keep_prob, seed=seed,
-                          block=block, m=m),
-        grid=(nblocks,),
-        in_specs=[_block_spec(block), pl.BlockSpec((1,), lambda i: (0,))],
-        out_specs=[_block_spec(block)] * 3,
-        out_shape=[jax.ShapeDtypeStruct((nblocks, block), jnp.uint32)] * 3,
-        interpret=interpret,
-    )(xp.reshape(nblocks, block), worker)
-    idx = jnp.arange(nblocks * block, dtype=jnp.uint32)
-    flat = lambda a: a.reshape(-1)
-    return _pack_sparse(idx, flat(sign), flat(mag), flat(valid),
-                        iw=iw, m=m, interpret=interpret)
+    """``worker`` is an int32 scalar operand (SMEM in the kernel), so the
+    per-worker path maps it without recompiling."""
+    vals = bernk_compress(_pad_to(x.astype(jnp.float32), block),
+                          keep_prob=keep_prob, seed=seed, worker=worker,
+                          block=block, interpret=interpret)
+    bits = jax.lax.bitcast_convert_type(vals, jnp.uint32)
+    return _pack_sparse(bits, None, iw=iw, m=m, interpret=interpret)
 
 
 @functools.partial(
@@ -304,36 +219,28 @@ def _mask_device(x, worker, *, keep_prob: float, seed: int, m: MagDType,
 )
 def _topk_device(x, *, k_per_block: int, m: MagDType, block: int, iw: int,
                  interpret: bool):
-    xp, d = _pad_to(x.astype(jnp.float32), block)
-    nblocks = xp.shape[-1] // block
+    assert block % 128 == 0, block
+    xp = _pad_to(x.astype(jnp.float32), block)
+    rows, nrows = row_tiling(xp.shape[-1] // block, block, _ROW_TILE)
+    xp = _pad_to(xp, nrows * block).reshape(nrows, block)
     ks = min(k_per_block, block)
-    out_spec = pl.BlockSpec((1, ks), lambda i: (i, 0))
-    idx, sign, mag, valid = pl.pallas_call(
-        functools.partial(_topk_streams_kernel, k=k_per_block, block=block, m=m),
-        grid=(nblocks,),
-        in_specs=[_block_spec(block)],
-        out_specs=[out_spec] * 4,
-        out_shape=[jax.ShapeDtypeStruct((nblocks, ks), jnp.uint32)] * 4,
+    out_spec = pl.BlockSpec((rows, ks), lambda i: (i, 0))
+    idx, bits = pl.pallas_call(
+        functools.partial(_topk_streams_kernel, k=k_per_block, block=block),
+        grid=(nrows // rows,),
+        in_specs=[pl.BlockSpec((rows, block), lambda i: (i, 0))],
+        out_specs=[out_spec] * 2,
+        out_shape=[jax.ShapeDtypeStruct((nrows, ks), jnp.uint32)] * 2,
         interpret=interpret,
-    )(xp.reshape(nblocks, block))
-    flat = lambda a: a.reshape(-1)
-    return _pack_sparse(flat(idx), flat(sign), flat(mag), flat(valid),
-                        iw=iw, m=m, interpret=interpret)
+    )(xp)
+    return _pack_sparse(bits.reshape(-1), idx.reshape(-1), iw=iw, m=m,
+                        interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("m", "block", "interpret"))
-def _dense_device(x, *, m: MagDType, block: int, interpret: bool):
-    xp, d = _pad_to(x.astype(jnp.float32), block)
-    nblocks = xp.shape[-1] // block
-    bits = pl.pallas_call(
-        functools.partial(_dense_bits_kernel, m=m),
-        grid=(nblocks,),
-        in_specs=[_block_spec(block)],
-        out_specs=_block_spec(block),
-        out_shape=jax.ShapeDtypeStruct((nblocks, block), jnp.uint32),
-        interpret=interpret,
-    )(xp.reshape(nblocks, block))
-    return _pack_stream(bits.reshape(-1)[:d], width=MAG_BITS[m], interpret=interpret)
+@functools.partial(jax.jit, static_argnames=("m", "interpret"))
+def _dense_device(x, *, m: MagDType, interpret: bool):
+    return _pack.pack_bits_device(_valbits(x.astype(jnp.float32), m),
+                                  width=MAG_BITS[m], interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -347,28 +254,27 @@ def _assemble_sparse(d: int, m: MagDType, count, widx, wsign, wmag) -> bytes:
     if count == 0:
         return head
     iw = index_width(d)
-    # one transfer for all three streams, then zero-copy trims
-    words = np.asarray(jnp.concatenate([widx, wsign, wmag]))
-    o1, o2 = widx.shape[0], widx.shape[0] + wsign.shape[0]
+    streams = jax.device_get((widx, wsign, wmag))
     return head + b"".join(
-        words[o : o + bs.n_words(count, w)].tobytes()
-        for o, w in ((0, iw), (o1, 1), (o2, MAG_BITS[m]))
+        s[: bs.n_words(count, w)].tobytes()
+        for s, w in zip(streams, (iw, 1, MAG_BITS[m]))
     )
 
 
 def sparse_encode(x, *, mag="fp32", block: int = 1024,
                   interpret: bool | None = None) -> bytes:
     """SPARSE-codec encode of an already-sparsified vector, fully on
-    device. Byte-identical to ``wire.encode_sparse(np.asarray(x))``."""
+    device. Byte-identical to ``wire.encode_sparse(np.asarray(x))``.
+
+    ``block`` is the compression block of the compressor paths; a vector
+    that arrives sparsified has none, so it does not change the stream."""
     m = mag_dtype(mag)
     x = jnp.asarray(x)
     d = x.shape[-1]
     if d == 0:
         return pack_header(CodecID.SPARSE, 0) + _SPARSE_PAYLOAD.pack(int(m), 0)
     count, widx, wsign, wmag = _sparse_device(
-        x, m=m, block=block, iw=index_width(d),
-        interpret=resolve_interpret(interpret),
-    )
+        x, m=m, iw=index_width(d), interpret=resolve_interpret(interpret))
     return _assemble_sparse(d, m, count, widx, wsign, wmag)
 
 
@@ -400,7 +306,7 @@ def mask_encode(x, *, keep_prob: float, seed: int, worker: int = 0,
     x = jnp.asarray(x)
     d = x.shape[-1]
     count, widx, wsign, wmag = _mask_device(
-        x, jnp.asarray([worker], jnp.int32), keep_prob=keep_prob, seed=seed,
+        x, jnp.int32(worker), keep_prob=keep_prob, seed=seed,
         m=m, block=block, iw=index_width(d),
         interpret=resolve_interpret(interpret),
     )
@@ -410,11 +316,11 @@ def mask_encode(x, *, keep_prob: float, seed: int, worker: int = 0,
 def dense_encode(x, *, mag="fp32", block: int = 1024,
                  interpret: bool | None = None) -> bytes:
     """DENSE-codec encode on device (full-sync broadcast rounds).
-    Byte-identical to ``wire.encode_dense(np.asarray(x))``."""
+    Byte-identical to ``wire.encode_dense(np.asarray(x))``. ``block`` as
+    in :func:`sparse_encode`."""
     m = mag_dtype(mag)
     x = jnp.asarray(x)
-    words = _dense_device(x, m=m, block=block,
-                          interpret=resolve_interpret(interpret))
+    words = _dense_device(x, m=m, interpret=resolve_interpret(interpret))
     return (
         pack_header(CodecID.DENSE, x.shape[-1])
         + _DENSE_PAYLOAD.pack(int(m))
@@ -427,51 +333,26 @@ def dense_encode(x, *, mag="fp32", block: int = 1024,
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnames=("m", "block", "iw", "interpret"))
-def _rows_device(X, *, m: MagDType, block: int, iw: int, interpret: bool):
-    """vmap of the sparse pipeline over message rows [n, d]."""
-    return jax.vmap(
-        lambda row: _sparse_device(row, m=m, block=block, iw=iw,
-                                   interpret=interpret)
-    )(X)
-
-
 @functools.partial(
     jax.jit, static_argnames=("keep_prob", "seed", "m", "block", "iw", "interpret")
 )
 def _workers_device(x, workers, *, keep_prob: float, seed: int, m: MagDType,
                     block: int, iw: int, interpret: bool):
-    """vmap of the fused mask pipeline over the worker-id operand: one
-    shared input vector, N packed streams."""
-    return jax.vmap(
+    """The fused mask pipeline mapped over the worker ids: one shared input
+    vector, N packed streams."""
+    return jax.lax.map(
         lambda w: _mask_device(x, w, keep_prob=keep_prob, seed=seed, m=m,
                                block=block, iw=iw, interpret=interpret),
-        in_axes=(0,),
-    )(workers)
-
-
-def _assemble_rows(d, m, counts, widx, wsign, wmag):
-    return [
-        _assemble_sparse(d, m, counts[i], widx[i], wsign[i], wmag[i])
-        for i in range(len(counts))
-    ]
+        workers)
 
 
 def encode_rows(X, *, mag="fp32", block: int = 1024,
                 interpret: bool | None = None) -> list[bytes]:
-    """Batched :func:`sparse_encode` over message rows ``X [n, d]`` —
-    one vmapped device pass, n send-ready buffers."""
-    m = mag_dtype(mag)
-    X = jnp.asarray(X)
-    n, d = X.shape
-    if d == 0:
-        head = pack_header(CodecID.SPARSE, 0) + _SPARSE_PAYLOAD.pack(int(m), 0)
-        return [head] * n
-    counts, widx, wsign, wmag = _rows_device(
-        X, m=m, block=block, iw=index_width(d),
-        interpret=resolve_interpret(interpret),
-    )
-    return _assemble_rows(d, m, np.asarray(counts), widx, wsign, wmag)
+    """:func:`sparse_encode` of each message row of ``X`` ([n, d], or an
+    iterable of [d] rows), one row at a time: device memory holds one
+    row's streams in flight, not n (the n rows of a real model's broadcast
+    do not fit at once). ``block`` as in :func:`sparse_encode`."""
+    return [sparse_encode(row, mag=mag, interpret=interpret) for row in X]
 
 
 def encode_per_worker(x, *, n_workers: int, keep_prob: float, seed: int,
@@ -493,9 +374,10 @@ def encode_per_worker(x, *, n_workers: int, keep_prob: float, seed: int,
         return [buf] * n_workers
     if mode != "ind":
         raise ValueError(f"encode_per_worker mode must be ind|same, got {mode!r}")
-    workers = jnp.arange(n_workers, dtype=jnp.int32).reshape(n_workers, 1)
+    workers = jnp.arange(n_workers, dtype=jnp.int32)
     counts, widx, wsign, wmag = _workers_device(
         x, workers, keep_prob=keep_prob, seed=seed, m=m, block=block,
         iw=index_width(d), interpret=resolve_interpret(interpret),
     )
-    return _assemble_rows(d, m, np.asarray(counts), widx, wsign, wmag)
+    return [_assemble_sparse(d, m, c, widx[i], wsign[i], wmag[i])
+            for i, c in enumerate(np.asarray(counts))]

@@ -25,15 +25,19 @@ def _l1_subgrad_kernel(a_ref, x_ref, g_ref):
     i = pl.program_id(0)
     a = a_ref[...]  # [R, d]
     x = x_ref[...]  # [1, d]
-    y = jnp.dot(a, x[0], preferred_element_type=jnp.float32)  # [R]
-    s = jnp.where(y >= 0, 1.0, -1.0)
-    contrib = jnp.dot(s, a, preferred_element_type=jnp.float32)  # [d]
+    # both products stay 2-D (row vectors), the forms Mosaic lowers to the MXU
+    y = jax.lax.dot_general(x, a, (((1,), (1,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)  # [1, R]
+    s = jnp.where(y >= 0, 1.0, -1.0).astype(a.dtype)
+    contrib = jnp.dot(s, a, precision=jax.lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32)  # [1, d]
 
     @pl.when(i == 0)
     def _init():
         g_ref[...] = jnp.zeros_like(g_ref)
 
-    g_ref[...] += contrib[None, :].astype(g_ref.dtype)
+    g_ref[...] += contrib.astype(g_ref.dtype)
 
 
 def l1_subgrad(A: jax.Array, x: jax.Array, *, row_block: int = 128,
@@ -42,12 +46,14 @@ def l1_subgrad(A: jax.Array, x: jax.Array, *, row_block: int = 128,
     interpret = resolve_interpret(interpret)
     m, d = A.shape
     assert m % row_block == 0 and d % 128 == 0, (m, d)
-    grid = (m // row_block,)
+    rows = row_block  # halved while an A tile exceeds 2 MiB of VMEM
+    while rows % 16 == 0 and rows * d > 1 << 19:
+        rows //= 2
     out = pl.pallas_call(
         _l1_subgrad_kernel,
-        grid=grid,
+        grid=(m // rows,),
         in_specs=[
-            pl.BlockSpec((row_block, d), lambda i: (i, 0)),
+            pl.BlockSpec((rows, d), lambda i: (i, 0)),
             pl.BlockSpec((1, d), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, d), lambda i: (0, 0)),

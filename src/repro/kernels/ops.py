@@ -18,7 +18,6 @@ from . import pack as _pack
 from . import permk as _permk
 from . import randk as _randk
 from . import topk as _topk
-from .runtime import default_interpret as _default_interpret
 
 
 def _pad_to(x, mult):
@@ -29,7 +28,6 @@ def _pad_to(x, mult):
 
 @partial(jax.jit, static_argnames=("k_per_block", "block", "interpret"))
 def block_topk(x, *, k_per_block: int, block: int = 1024, interpret: bool | None = None):
-    interpret = _default_interpret() if interpret is None else interpret
     xp, d = _pad_to(x, block)
     out = _topk.block_topk_compress(xp, k_per_block=k_per_block, block=block, interpret=interpret)
     return out[:d]
@@ -38,7 +36,6 @@ def block_topk(x, *, k_per_block: int, block: int = 1024, interpret: bool | None
 @partial(jax.jit, static_argnames=("keep_prob", "seed", "worker", "block", "interpret"))
 def bernk(x, *, keep_prob: float, seed: int, worker: int = 0, block: int = 1024,
           interpret: bool | None = None):
-    interpret = _default_interpret() if interpret is None else interpret
     xp, d = _pad_to(x, block)
     out = _randk.bernk_compress(
         xp, keep_prob=keep_prob, seed=seed, worker=worker, block=block, interpret=interpret
@@ -49,7 +46,6 @@ def bernk(x, *, keep_prob: float, seed: int, worker: int = 0, block: int = 1024,
 @partial(jax.jit, static_argnames=("n", "worker", "block", "interpret"))
 def rotk_apply(w, delta, rotation, *, n: int, worker: int, block: int = 1024,
                interpret: bool | None = None):
-    interpret = _default_interpret() if interpret is None else interpret
     wp, d = _pad_to(w, block)
     dp, _ = _pad_to(delta, block)
     out = _permk.rotk_apply(wp, dp, rotation, n=n, worker=worker, block=block, interpret=interpret)
@@ -58,29 +54,21 @@ def rotk_apply(w, delta, rotation, *, n: int, worker: int, block: int = 1024,
 
 @partial(jax.jit, static_argnames=("width", "interpret"))
 def pack_bits(values, *, width: int, interpret: bool | None = None):
-    """Bit-pack ``values`` ([n] uint32, each < 2**width) into uint32 words
-    (wire/bitstream.py layout). Zero-pads to block multiples and trims the
-    output to ceil(n*width/32) words."""
-    interpret = _default_interpret() if interpret is None else interpret
-    vpb, _ = _pack.word_block(width)
-    vp, n = _pad_to(values.astype(jnp.uint32), vpb)
-    nwords = -(-n * width // 32)
-    return _pack.pack_bits_device(vp, width=width, interpret=interpret)[:nwords]
+    """Bit-pack ``values`` ([n] uint32, each < 2**width) into the
+    ceil(n*width/32) uint32 words of the wire/bitstream.py layout."""
+    return _pack.pack_bits_device(values, width=width, interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("width", "count", "interpret"))
 def unpack_bits(words, *, width: int, count: int, interpret: bool | None = None):
     """Inverse of :func:`pack_bits`: read ``count`` values of ``width`` bits."""
-    interpret = _default_interpret() if interpret is None else interpret
-    _, wpb = _pack.word_block(width)
-    wp, _ = _pad_to(words.astype(jnp.uint32), wpb)
-    return _pack.unpack_bits_device(wp, width=width, interpret=interpret)[:count]
+    return _pack.unpack_bits_device(words, width=width, count=count,
+                                    interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("row_block", "interpret"))
 def l1_subgrad(A, x, *, row_block: int = 128, interpret: bool | None = None):
     """g = A^T sign(A x), padded to (row_block, 128) tiles. A: [m, d]."""
-    interpret = _default_interpret() if interpret is None else interpret
     m, d = A.shape
     pm, pd = (-m) % row_block, (-d) % 128
     Ap = jnp.pad(A, ((0, pm), (0, pd)))

@@ -6,110 +6,116 @@ sparse downlink message can be packed before ever touching the host
 (DESIGN.md §3.4). Bit-interchangeable with the host numpy codec — asserted
 in tests/test_wire.py.
 
-Tiling: blocks are chosen word-aligned (values_per_block * width % 32 == 0),
-so no value crosses a block boundary and each grid step packs its own word
-range independently. Inside a block, value ``i`` contributes a low part to
-word ``(i*width) // 32`` and (when it straddles) a high part to the next
-word; the kernel accumulates both with a broadcast compare-and-sum — pure
-vector ops, no scatter — which lowers to VPU code on TPU.
+Tiling: 32 values of ``width`` bits fill exactly ``width`` words, so the
+stream splits into independent word-aligned groups of 32 values. A grid step
+takes 128 runs of 4096 values (a ``[128, 4096]`` block: 128 groups per run)
+and emits their ``128 * width`` word rows of 128 lanes. Inside the step the
+runs are moved onto lanes with 128 x 128 XLU transposes, so that every
+value slot ``i`` of the 128 groups of all 128 runs is one ``[128, 128]``
+strided load; each output word is an OR of a few such shifted loads, stored
+with a stride of ``width`` rows and transposed back into stream order. All
+vector work is whole-vreg and every index is static: no scatter, no gather,
+no cross-lane shuffles beyond the transposes, and both blocks are dense
+(8, 128)-tiled arrays, the layout Mosaic needs.
 """
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .runtime import resolve_interpret
 
-
-def word_block(width: int, target: int = 512) -> tuple[int, int]:
-    """(values_per_block, words_per_block): the smallest word-aligned value
-    group, replicated up to ~``target`` values per grid step."""
-    g = math.gcd(width, 32)
-    gv, gw = 32 // g, width // g  # values / words per aligned group
-    reps = max(1, target // gv)
-    return gv * reps, gw * reps
+GROUP = 32                 # values per word-aligned group: 32 values fill `width` words
+RUN = 128 * GROUP          # values per block row (128 groups)
+BLOCK = 128 * RUN          # values per grid step
 
 
-def _split_parts(v, width: int):
-    """Per-value (low word part, high word part, local word index)."""
-    vpb = v.shape[-1]
-    i = jax.lax.broadcasted_iota(jnp.int32, v.shape, len(v.shape) - 1)
-    pos = i * width
-    word = pos // 32
-    off = (pos % 32).astype(jnp.uint32)
-    lo = v << off  # uint32: overflow bits drop, as intended
-    hi = (v >> jnp.uint32(1)) >> (jnp.uint32(31) - off)  # v >> (32-off); off=0 -> 0
-    return lo, hi, word
+def _pack_kernel(v_ref, out_ref, vt_ref, wt_ref, *, width: int):
+    # vt[32A + i, r] = value i of group A in run r (runs moved onto lanes)
+    for a in range(RUN // 128):
+        vt_ref[128 * a:128 * (a + 1), :] = v_ref[:, 128 * a:128 * (a + 1)].T
+    # value i starts at bit i*width: its low part lands in word
+    # (i*width)//32 at offset (i*width)%32, the bits past 32 in the next word
+    for k in range(width):
+        word = None
+        for i in range(GROUP):
+            lo_word, off = divmod(i * width, 32)
+            if lo_word == k:
+                part = vt_ref[pl.ds(i, 128, stride=GROUP), :] << off
+            elif lo_word + 1 == k and off + width > 32:
+                part = vt_ref[pl.ds(i, 128, stride=GROUP), :] >> (32 - off)
+            else:
+                continue
+            word = part if word is None else word | part
+        wt_ref[pl.ds(k, 128, stride=width), :] = word  # wt[A*width + k, r]
+    # run r's words are wt[:, r] in stream order: 128-word chunk c of it is
+    # output row r*width + c
+    for c in range(width):
+        out_ref[pl.ds(c, 128, stride=width), :] = wt_ref[128 * c:128 * (c + 1), :].T
 
 
-def _pack_kernel(v_ref, out_ref, *, width: int, wpb: int):
-    v = v_ref[...].astype(jnp.uint32)  # [1, vpb]
-    lo, hi, word = _split_parts(v, width)
-    j = jax.lax.broadcasted_iota(jnp.int32, (1, v.shape[-1], wpb), 2)
-    wcol = word[..., None]  # [1, vpb, 1]
-    acc = jnp.where(j == wcol, lo[..., None], jnp.uint32(0))
-    acc = acc + jnp.where(j == wcol + 1, hi[..., None], jnp.uint32(0))
-    out_ref[...] = jnp.sum(acc, axis=1).astype(jnp.uint32)  # [1, wpb]
+def _unpack_kernel(w_ref, out_ref, vt_ref, wt_ref, *, width: int):
+    for c in range(width):
+        wt_ref[128 * c:128 * (c + 1), :] = w_ref[pl.ds(c, 128, stride=width), :].T
+    for i in range(GROUP):
+        k, off = divmod(i * width, 32)
+        v = wt_ref[pl.ds(k, 128, stride=width), :] >> off
+        if off + width > 32:
+            v = v | (wt_ref[pl.ds(k + 1, 128, stride=width), :] << (32 - off))
+        if width < 32:
+            v = v & ((1 << width) - 1)
+        vt_ref[pl.ds(i, 128, stride=GROUP), :] = v
+    for a in range(RUN // 128):
+        out_ref[:, 128 * a:128 * (a + 1)] = vt_ref[128 * a:128 * (a + 1), :].T
 
 
-def _unpack_kernel(w_ref, out_ref, *, width: int, vpb: int):
-    w = w_ref[...].astype(jnp.uint32)  # [1, wpb]
-    wpb = w.shape[-1]
-    i = jax.lax.broadcasted_iota(jnp.int32, (1, vpb), 1)
-    pos = i * width
-    word = pos // 32
-    off = (pos % 32).astype(jnp.uint32)
-    j = jax.lax.broadcasted_iota(jnp.int32, (1, vpb, wpb), 2)
-    wcol = word[..., None]
-    cur = jnp.sum(jnp.where(j == wcol, w[:, None, :], jnp.uint32(0)), axis=2)
-    nxt = jnp.sum(jnp.where(j == wcol + 1, w[:, None, :], jnp.uint32(0)), axis=2)
-    lo = cur >> off
-    hi = (nxt << jnp.uint32(1)) << (jnp.uint32(31) - off)  # nxt << (32-off); off=0 -> 0
-    mask = jnp.uint32(0xFFFFFFFF if width == 32 else (1 << width) - 1)
-    out_ref[...] = ((lo | hi) & mask).astype(jnp.uint32)
+def _blocked_call(kernel, x, in_block, out_block, width: int, interpret: bool):
+    steps = x.shape[0] // in_block[0]
+    return pl.pallas_call(
+        functools.partial(kernel, width=width),
+        grid=(steps,),
+        in_specs=[pl.BlockSpec(in_block, lambda j: (j, 0))],
+        out_specs=pl.BlockSpec(out_block, lambda j: (j, 0)),
+        out_shape=jax.ShapeDtypeStruct((steps * out_block[0], out_block[1]),
+                                       jnp.uint32),
+        scratch_shapes=[pltpu.VMEM((RUN, 128), jnp.uint32),
+                        pltpu.VMEM((128 * width, 128), jnp.uint32)],
+        interpret=interpret,
+    )(x)
 
 
 def pack_bits_device(values: jax.Array, *, width: int,
                      interpret: bool | None = None) -> jax.Array:
-    """values: [n] uint32 (n % values_per_block == 0). Returns packed words.
+    """values: [n] uint32, each < 2**width. Returns the ceil(n*width/32)
+    packed words.
 
     ``interpret=None`` auto-detects via kernels/runtime.py (compiled on a
     real TPU, interpret under CPU tests; ``REPRO_PALLAS_INTERPRET`` forces).
     """
-    interpret = resolve_interpret(interpret)
-    vpb, wpb = word_block(width)
     n = values.shape[-1]
-    assert n % vpb == 0, (n, vpb)
-    nblocks = n // vpb
-    out = pl.pallas_call(
-        functools.partial(_pack_kernel, width=width, wpb=wpb),
-        grid=(nblocks,),
-        in_specs=[pl.BlockSpec((1, vpb), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, wpb), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nblocks, wpb), jnp.uint32),
-        interpret=interpret,
-    )(values.reshape(nblocks, vpb))
-    return out.reshape(nblocks * wpb)
+    if n == 0:
+        return jnp.zeros((0,), jnp.uint32)
+    steps = -(-n // BLOCK)
+    v = jnp.pad(values.astype(jnp.uint32), (0, steps * BLOCK - n))
+    out = _blocked_call(_pack_kernel, v.reshape(steps * 128, RUN), (128, RUN),
+                        (128 * width, 128), width, resolve_interpret(interpret))
+    return out.reshape(-1)[:-(-n * width // 32)]
 
 
-def unpack_bits_device(words: jax.Array, *, width: int,
+def unpack_bits_device(words: jax.Array, *, width: int, count: int,
                        interpret: bool | None = None) -> jax.Array:
-    """words: [nw] uint32 (nw % words_per_block == 0). Returns unpacked values."""
-    interpret = resolve_interpret(interpret)
-    vpb, wpb = word_block(width)
-    nw = words.shape[-1]
-    assert nw % wpb == 0, (nw, wpb)
-    nblocks = nw // wpb
-    out = pl.pallas_call(
-        functools.partial(_unpack_kernel, width=width, vpb=vpb),
-        grid=(nblocks,),
-        in_specs=[pl.BlockSpec((1, wpb), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, vpb), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nblocks, vpb), jnp.uint32),
-        interpret=interpret,
-    )(words.reshape(nblocks, wpb))
-    return out.reshape(nblocks * vpb)
+    """Inverse of :func:`pack_bits_device`: read ``count`` values of
+    ``width`` bits from ``words`` ([nw] uint32; missing words read as 0)."""
+    if count == 0:
+        return jnp.zeros((0,), jnp.uint32)
+    steps = -(-count // BLOCK)
+    need = steps * BLOCK // 32 * width
+    w = words.astype(jnp.uint32)[:need]
+    w = jnp.pad(w, (0, need - w.shape[-1]))
+    out = _blocked_call(_unpack_kernel, w.reshape(-1, 128), (128 * width, 128),
+                        (128, RUN), width, resolve_interpret(interpret))
+    return out.reshape(-1)[:count]

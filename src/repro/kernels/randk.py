@@ -14,8 +14,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .runtime import resolve_interpret
+from .runtime import resolve_interpret, row_tiling
 
 _M1 = 2654435761
 _M2 = 2246822519
@@ -32,33 +33,39 @@ def hash_uniform(idx: jax.Array, seed, worker) -> jax.Array:
     h = h ^ (h >> 13)
     h = h * m1
     h = h ^ (h >> 16)
-    return h.astype(jnp.float32) * (1.0 / 4294967296.0)
+    # float32(h) as two exact 16-bit halves and one rounding of their sum:
+    # bit-identical to h.astype(float32), which Mosaic cannot lower
+    hi = jax.lax.bitcast_convert_type(h >> 16, jnp.int32).astype(jnp.float32)
+    lo = jax.lax.bitcast_convert_type(h & jnp.uint32(0xFFFF), jnp.int32).astype(jnp.float32)
+    return (hi * 65536.0 + lo) * (1.0 / 4294967296.0)
 
 
-def _bernk_kernel(x_ref, out_ref, *, keep_prob: float, seed: int, worker: int, block: int):
+def _bernk_kernel(x_ref, w_ref, out_ref, *, keep_prob: float, seed: int, block: int):
     i = pl.program_id(0)
-    x = x_ref[...]  # [1, b]
-    local = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-    gidx = (i * block + local).astype(jnp.uint32)
-    u = hash_uniform(gidx, seed, worker)
-    keep = u < keep_prob
-    out_ref[...] = jnp.where(keep, x / keep_prob, 0.0).astype(out_ref.dtype)
+    x = x_ref[...]  # [R, b]: R compression blocks of b
+    rows, lanes = x.shape
+    row = i * rows + jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    gidx = (row * block + jax.lax.broadcasted_iota(jnp.int32, x.shape, 1))
+    u = hash_uniform(gidx.astype(jnp.uint32), seed, w_ref[0])
+    out_ref[...] = jnp.where(u < keep_prob, x / keep_prob, 0.0).astype(out_ref.dtype)
 
 
-def bernk_compress(x: jax.Array, *, keep_prob: float, seed: int, worker: int = 0,
+def bernk_compress(x: jax.Array, *, keep_prob: float, seed: int, worker=0,
                    block: int = 1024, interpret: bool | None = None) -> jax.Array:
-    interpret = resolve_interpret(interpret)
+    """x: [d] (d % block == 0). ``worker`` is an int or a traced int32
+    scalar: it reaches the kernel through SMEM, so one compiled kernel
+    serves every worker of a per-worker fan-out."""
     d = x.shape[-1]
-    assert d % block == 0, (d, block)
-    nblocks = d // block
+    assert d % block == 0 and block % 128 == 0, (d, block)
+    rows, nrows = row_tiling(d // block, block)
+    spec = pl.BlockSpec((rows, block), lambda i: (i, 0))
     out = pl.pallas_call(
-        functools.partial(
-            _bernk_kernel, keep_prob=keep_prob, seed=seed, worker=worker, block=block
-        ),
-        grid=(nblocks,),
-        in_specs=[pl.BlockSpec((1, block), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, block), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nblocks, block), x.dtype),
-        interpret=interpret,
-    )(x.reshape(nblocks, block))
-    return out.reshape(d)
+        functools.partial(_bernk_kernel, keep_prob=keep_prob, seed=seed, block=block),
+        grid=(nrows // rows,),
+        in_specs=[spec, pl.BlockSpec(memory_space=pltpu.SMEM)],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((nrows, block), x.dtype),
+        interpret=resolve_interpret(interpret),
+    )(jnp.pad(x, (0, nrows * block - d)).reshape(nrows, block),
+      jnp.asarray(worker, jnp.int32).reshape(1))
+    return out.reshape(-1)[:d]

@@ -82,7 +82,8 @@ def run_one(arch: str, shape: str, *, multi_pod: bool = False, downlink: str = "
     coll_dev = totals["coll_total"]
     cfg = built.meta["cfg"]
     mf = roofline.model_flops(cfg, built.meta["kind"], built.meta["global_batch"], built.meta["seq"])
-    terms = roofline.roofline_terms(flops_dev, bytes_dev, coll_dev)
+    terms = roofline.roofline_terms(flops_dev, bytes_dev, coll_dev,
+                                    device_kind=roofline.TARGET_KIND)
     rec = {
         "arch": arch,
         "shape": shape,

@@ -30,7 +30,8 @@ def reanalyze(dirpath: str, out_dir: str | None = None):
         rec["collective_bytes_per_device"] = totals["coll"]
         rec["collective_total_per_device"] = totals["coll_total"]
         rec["roofline"] = roofline.roofline_terms(
-            totals["flops"], totals["bytes"], totals["coll_total"]
+            totals["flops"], totals["bytes"], totals["coll_total"],
+            device_kind=roofline.TARGET_KIND,
         )
         if rec.get("model_flops") and totals["flops"]:
             rec["useful_flops_ratio"] = rec["model_flops"] / (totals["flops"] * rec["chips"])

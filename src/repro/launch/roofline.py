@@ -1,14 +1,10 @@
 """Roofline terms from compiled dry-run artifacts (DESIGN.md §6).
 
-Hardware constants (TPU v5e target):
-    peak bf16 compute   197e12 FLOP/s per chip
-    HBM bandwidth       819e9  B/s  per chip
-    ICI link bandwidth  50e9   B/s  per link per chip
-
-Terms per (arch × shape × mesh):
-    compute    = HLO_FLOPs   / (chips * PEAK_FLOPS)
-    memory     = HLO_bytes   / (chips * HBM_BW)
-    collective = coll_bytes  / (chips * ICI_BW)
+Peaks come from :data:`PEAKS`, one entry per ``device_kind``; a kind that
+is not in it is an error, never a default. Terms per (arch × shape × mesh):
+    compute    = HLO_FLOPs   / (chips * peak FLOP/s)
+    memory     = HLO_bytes   / (chips * HBM bytes/s)
+    collective = coll_bytes  / (chips * ICI bytes/s per link)
 
 ``collective_bytes`` parses the optimized HLO text and sums operand sizes of
 all-gather / all-reduce / reduce-scatter / all-to-all / collective-permute
@@ -19,9 +15,22 @@ from __future__ import annotations
 import re
 from typing import Dict
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+# Published per-chip peaks, keyed by ``jax.devices()[0].device_kind``.
+# "TPU v5 lite" is TPU v5e. Source: Google Cloud documentation, "TPU v5e":
+# 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s of ICI (50 GB/s per link).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+# the chip the dry-run's production meshes stand for (DESIGN.md §5)
+TARGET_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """Peaks of one chip of ``device_kind``; raises on an unknown kind."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}; "
+                       "add them to roofline.PEAKS with their source")
+    return PEAKS[device_kind]
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -75,13 +84,14 @@ def collective_bytes(hlo_text: str) -> Dict[str, float]:
 
 
 def roofline_terms(flops_per_device: float, bytes_per_device: float,
-                   coll_bytes_per_device: float) -> dict:
+                   coll_bytes_per_device: float, *, device_kind: str) -> dict:
     """Terms in seconds from PER-DEVICE totals (the compiled module is the
     per-device SPMD program; global = per-device totals balanced across chips,
     so per-device/peak IS the global step-time bound per term)."""
-    compute = flops_per_device / PEAK_FLOPS
-    memory = bytes_per_device / HBM_BW
-    collective = coll_bytes_per_device / ICI_BW
+    pk = peaks(device_kind)
+    compute = flops_per_device / pk["flops"]
+    memory = bytes_per_device / pk["hbm_bw"]
+    collective = coll_bytes_per_device / pk["ici_bw"]
     terms = {"compute_s": compute, "memory_s": memory, "collective_s": collective}
     dominant = max(terms, key=terms.get)
     terms["dominant"] = dominant

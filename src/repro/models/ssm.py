@@ -10,7 +10,10 @@ Decode is a single recurrence step on an O(1) state — these layers are what
 makes ``long_500k`` native for rwkv6/zamba2 (DESIGN.md §4).
 
 Numerical notes:
-* Mamba2 decay exponents are always <= 0 within the chunk quadratic — safe.
+* Mamba2 decay exponents L_t - L_s are <= 0 only on the causal half of the
+  chunk quadratic; the other half grows with the chunk (~chunk * dt) and
+  overflows exp at chunk 256. It is masked to -inf *before* the exp, so the
+  backward pass never multiplies a zero cotangent by inf.
 * RWKV6 per-channel decays are clamped to log w in [-2, -1e-6] and the
   intra-chunk factors are stabilized around the chunk-midpoint cumulative
   decay (documented simplification; chunk=32).
@@ -112,9 +115,10 @@ def mamba_apply(cfg: ModelConfig, params, x):
 
     # ---- intra-chunk quadratic: scores[t,s] = (C_t.B_s) e^{L_t-L_s} (s<=t)
     CB = jnp.einsum("bctn,bcsn->bcts", C_c, B_c)  # [B,nc,Q,Q]
-    dec = jnp.exp(L[:, :, :, None, :] - L[:, :, None, :, :])  # [B,nc,Q,Q,H]
     mask = jnp.tril(jnp.ones((Q, Q), bool))
-    scores = CB[..., None] * jnp.where(mask[None, None, :, :, None], dec, 0.0)
+    seg = L[:, :, :, None, :] - L[:, :, None, :, :]  # [B,nc,Q,Q,H]
+    dec = jnp.exp(jnp.where(mask[None, None, :, :, None], seg, -jnp.inf))
+    scores = CB[..., None] * dec
     y_intra = jnp.einsum("bctsh,bcshd->bcthd", scores, x_c)
 
     # ---- inter-chunk recurrence over carried state [B,H,N,hd]

@@ -50,7 +50,12 @@ _RESERVOIR = 4096
 
 
 def environment(seed: Optional[int] = None) -> Dict[str, Any]:
-    """git rev / jax version / device kind — the provenance block."""
+    """git rev / jax version / device kind — the provenance block.
+
+    A failed device query is recorded as None, which keeps CPU artifacts
+    writable. It is no device check: code that must run on the chip
+    (chip_smoke.py) reads ``jax.devices()`` itself and fails without one.
+    """
     env: Dict[str, Any] = {"seed": seed}
     try:
         env["git_rev"] = subprocess.run(

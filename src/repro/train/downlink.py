@@ -20,10 +20,12 @@ zero interconnect bytes (shared-randomness materialization — DESIGN.md §2).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Tuple
 
 import jax
+import jax.flatten_util
 import jax.numpy as jnp
 
 from repro.core.comm_model import CommModel
@@ -43,6 +45,13 @@ def _leaf_rotk_mask(key, shape, n, worker):
 
 def _leaf_bern_mask(key, shape, keep_prob):
     return jax.random.uniform(key, shape) < keep_prob
+
+
+@jax.jit
+def _flat_f32(tree) -> Array:
+    """The tree raveled to one f32 vector (a full-sync message)."""
+    return jax.flatten_util.ravel_pytree(
+        jax.tree.map(lambda t: t.astype(jnp.float32), tree))[0]
 
 
 def tree_size(tree) -> int:
@@ -171,59 +180,58 @@ class MarinaPDownlink:
 
     def _dense_buf(self, server_new, mag, device_encode=None):
         """Serialize the full model for a sync broadcast."""
-        import jax.flatten_util  # noqa: F401  (registers jax.flatten_util)
         import numpy as np
 
         from repro import wire
 
-        flat = jax.flatten_util.ravel_pytree(
-            jax.tree.map(lambda t: t.astype(jnp.float32), server_new)
-        )[0]
+        flat = _flat_f32(server_new)
         if _use_device_encode(device_encode):
             from repro.kernels import encode as kenc
 
             return kenc.dense_encode(flat, mag=mag)
         return wire.encode_dense(np.asarray(flat), mag=mag)
 
+    @functools.partial(jax.jit, static_argnums=0)
+    def _message_row(self, k_comp, server_new, server_old, widx):
+        """Worker ``widx``'s compressed delta over the raveled tree (f32),
+        replaying :meth:`round`'s randomness."""
+        n = self.n_workers
+        parts = []
+        leaves_old = jax.tree.leaves(server_old)
+        for li, (xn, xo) in enumerate(zip(jax.tree.leaves(server_new), leaves_old)):
+            delta = (xn - xo).astype(jnp.float32)
+            lk = jax.random.fold_in(k_comp, li)
+            if self.mode == "perm":
+                m = _leaf_rotk_mask(lk, xn.shape, n, widx)
+                q = jnp.where(m, delta * n, 0)
+            elif self.mode == "ind":
+                m = _leaf_bern_mask(jax.random.fold_in(lk, widx), xn.shape, self.frac)
+                q = jnp.where(m, delta / self.frac, 0)
+            else:  # same
+                m = _leaf_bern_mask(lk, xn.shape, self.frac)
+                q = jnp.where(m, delta / self.frac, 0)
+            parts.append(q.reshape(-1))
+        return jnp.concatenate(parts)
+
     def _sparse_bufs(self, k_comp, server_new, server_old, mag,
                      device_encode=None):
-        """Per-worker compressed-delta buffers, replaying :meth:`round`'s
-        randomness over the raveled tree. 'same' mode encodes once and
-        repeats the buffer (every worker's message is identical); the
-        device path batches the per-worker rows through one vmapped
-        encode (kernels/encode.encode_rows)."""
+        """Per-worker compressed-delta buffers. 'same' mode encodes once and
+        repeats the buffer (every worker's message is identical). Rows are
+        built and encoded one at a time, so only one row is alive."""
         import numpy as np
 
         from repro import wire
 
-        n = self.n_workers
-        leaves_new, _ = jax.tree.flatten(server_new)
-        leaves_old = jax.tree.leaves(server_old)
-        rows = []
-        for widx in range(1 if self.mode == "same" else n):
-            parts = []
-            for li, (xn, xo) in enumerate(zip(leaves_new, leaves_old)):
-                delta = (xn - xo).astype(jnp.float32)
-                lk = jax.random.fold_in(k_comp, li)
-                if self.mode == "perm":
-                    m = _leaf_rotk_mask(lk, xn.shape, n, widx)
-                    q = jnp.where(m, delta * n, 0)
-                elif self.mode == "ind":
-                    m = _leaf_bern_mask(jax.random.fold_in(lk, widx), xn.shape, self.frac)
-                    q = jnp.where(m, delta / self.frac, 0)
-                else:  # same
-                    m = _leaf_bern_mask(lk, xn.shape, self.frac)
-                    q = jnp.where(m, delta / self.frac, 0)
-                parts.append(q.reshape(-1))
-            rows.append(jnp.concatenate(parts))
+        rows = (self._message_row(k_comp, server_new, server_old, w)
+                for w in range(1 if self.mode == "same" else self.n_workers))
         if _use_device_encode(device_encode):
             from repro.kernels import encode as kenc
 
-            bufs = kenc.encode_rows(jnp.stack(rows), mag=mag)
+            bufs = kenc.encode_rows(rows, mag=mag)
         else:
             bufs = [wire.encode_sparse(np.asarray(r), mag=mag) for r in rows]
         if self.mode == "same":
-            bufs = bufs * n
+            bufs = bufs * self.n_workers
         return bufs
 
     def measure_wire(self, key, server_new, server_old, *, mag="fp32",
@@ -344,8 +352,9 @@ class EF21PDownlink:
         return BlockTopK(k_per_block=self.k_per_block, block=self.block)
 
     def init_shift(self, server_params):
-        """w^0 = x^0; one tree — workers stay synchronized by construction."""
-        return jax.tree.map(lambda t: t, server_params)
+        """w^0 = x^0; one tree — workers stay synchronized by construction.
+        A copy, not the server's own buffers, so either may be donated."""
+        return jax.tree.map(jnp.array, server_params)
 
     def round(self, key, server_new, shift, force_sync=False):
         """``force_sync`` re-anchors the shift with a dense ``w := x``
@@ -416,7 +425,6 @@ class EF21PDownlink:
         result means the caller must pass ``force_sync=True`` to the next
         :meth:`round` (and roll its shift back — DESIGN.md §8.4).
         """
-        import jax.flatten_util  # noqa: F401
         import numpy as np
 
         from repro import wire
@@ -428,10 +436,7 @@ class EF21PDownlink:
             with maybe_span(tracker, "encode",
                             device=_use_device_encode(device_encode)):
                 if force_sync:
-                    flat = jax.flatten_util.ravel_pytree(
-                        jax.tree.map(
-                            lambda t: t.astype(jnp.float32), server_new)
-                    )[0]
+                    flat = _flat_f32(server_new)
                     if _use_device_encode(device_encode):
                         from repro.kernels import encode as kenc
 

@@ -213,7 +213,16 @@ def train_loop(
     tracker = tracker or obs.NullTracker()
     k_init, k_steps = jax.random.split(key)
     state = init_state(cfg, tcfg, downlink, optimizer, k_init)
-    step = jax.jit(make_train_step(cfg, tcfg, downlink, optimizer, lr_fn))
+    train_step = make_train_step(cfg, tcfg, downlink, optimizer, lr_fn)
+    # The broadcast reads the old server (MARINA-P) or the old shift
+    # (EF21-P) after the step. Every other buffer of the state is donated,
+    # so old and new optimizer moments and replicas are never resident
+    # together (at 326M parameters they would not fit one 16 GB chip).
+    kept = "workers" if isinstance(downlink, EF21PDownlink) else "server"
+    step = jax.jit(
+        lambda prev, rest, batch, key, fs: train_step({kept: prev, **rest}, batch, key, fs),
+        donate_argnums=1,
+    )
     fleet = None
     if transport is not None and downlink is not None:
         from repro.transport import FaultSpec, Fleet
@@ -228,26 +237,19 @@ def train_loop(
     for i in range(steps):
         batch = data.batch(i)
         k_step = jax.random.fold_in(k_steps, i)
-        prev_server = state["server"]
-        prev_workers = state.get("workers")
+        prev = state[kept]
+        rest = {k: v for k, v in state.items() if k != kept}
         was_forced = force_sync
         with span(tracker, "round", round=i, alg="train") as rsp:
             with tracker.time_block("train/step", step=i) as tb:
-                state, m = step(state, batch, k_step, force_sync)
+                state, m = step(prev, rest, batch, k_step, force_sync)
                 tb.block(m)
             if fleet is not None:
-                if isinstance(downlink, EF21PDownlink):
-                    res = downlink.broadcast_via(
-                        fleet, k_step, state["server"], prev_workers,
-                        mag=wire_mag, force_sync=force_sync, tracker=tracker,
-                        step=i,
-                    )
-                else:
-                    res = downlink.broadcast_via(
-                        fleet, k_step, state["server"], prev_server,
-                        mag=wire_mag, force_sync=force_sync, tracker=tracker,
-                        step=i,
-                    )
+                res = downlink.broadcast_via(
+                    fleet, k_step, state["server"], prev,
+                    mag=wire_mag, force_sync=force_sync, tracker=tracker,
+                    step=i,
+                )
                 force_sync = res["resync_needed"]
                 maybe_attr(rsp, full_sync=res["full_sync"],
                            resync_next=force_sync)

@@ -8,8 +8,19 @@ import types
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax
+import pytest
 
 jax.config.update("jax_default_matmul_precision", "highest")
+
+
+@pytest.fixture(autouse=True)
+def _release_compiled_programs():
+    """Drop JAX's compiled programs after each test. XLA:CPU maps every
+    kernel of a program into memory on its own, and an interpret-mode
+    Pallas pipeline is hundreds of kernels; a test worker that kept every
+    program alive would pass vm.max_map_count (65530) and abort."""
+    yield
+    jax.clear_caches()
 
 
 def pytest_configure(config):

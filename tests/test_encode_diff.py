@@ -231,9 +231,17 @@ def test_mask_encode_matches_seed_codec_bern():
 
 def test_ind_broadcast_uses_split_not_fold_in():
     """Regression guard for the PR-1 key-derivation fix: ind-mode per-worker
-    keys come from jax.random.split, NOT fold_in — the SPMD path
-    (core/distributed.py) regenerates the same masks from split keys, so a
-    silent revert here would desynchronize server and workers."""
+    keys come from jax.random.split, and the SPMD path (core/distributed.py)
+    regenerates the same masks from the same keys, so a silent drift on
+    either side would desynchronize server and workers.
+
+    With ``jax_threefry_partitionable`` on, ``split(k, n)[i]`` equals
+    ``fold_in(k, i)``, so the two derivations can no longer be told apart
+    by their output. The guard is therefore the split-derived reference
+    plus a cross-check against the SPMD round itself."""
+    from jax.sharding import Mesh
+
+    from repro.core import distributed, marina_p, problems, stepsizes
     from repro.core.compressors import RandK
     from repro.core.marina_p import make_broadcast
 
@@ -246,10 +254,23 @@ def test_ind_broadcast_uses_split_not_fold_in():
     keys = jax.random.split(key, n)
     want = np.asarray(jax.vmap(lambda kk: comp(kk, delta))(keys))
     np.testing.assert_array_equal(Q, want)
-    folded = np.stack([
-        np.asarray(comp(jax.random.fold_in(key, i), delta)) for i in range(n)
-    ])
-    assert not np.array_equal(Q, folded)
+    assert len({tuple(row) for row in (Q != 0)}) == n  # one key per worker
+
+    # one round (p=0: never a sync) of the reference and of the SPMD
+    # program on a one-device mesh: the same key must give every worker
+    # the same mask on both sides
+    prob = problems.generate_problem(n=n, d=d, noise_scale=1.0, seed=3)
+    mesh = Mesh(np.array(jax.devices()[:1]), ("workers",))
+    ss = stepsizes.Constant(gamma=0.05)
+    state = marina_p.init(prob.x0, n)
+    ref, _ = marina_p.make_step(prob, "ind", k=k, p=0.0, stepsize=ss)(state, key)
+    spmd = distributed.make_marina_p_spmd_step(
+        mesh, n=n, d=d, mode="ind", k=k, p=0.0, stepsize=ss)
+    _, W, _, _ = spmd(state.x, state.W, state.t, prob.A, key)
+    q_ref = np.asarray(ref.W - state.W)
+    q_spmd = np.asarray(W - state.W)
+    np.testing.assert_array_equal(q_ref != 0, q_spmd != 0)
+    np.testing.assert_allclose(q_spmd, q_ref, rtol=1e-5, atol=1e-6)
 
 
 # -- interpret / device-encode knobs ------------------------------------------

@@ -50,6 +50,19 @@ def test_mamba_chunk_size_invariance(chunks):
     np.testing.assert_allclose(np.asarray(y1, np.float32), np.asarray(y2, np.float32), rtol=1e-3, atol=1e-3)
 
 
+def test_mamba_grad_finite_at_published_chunk():
+    """At chunk 256 the acausal half of the decay exponents overflows exp;
+    it must be masked before the exp, or its zero cotangent times inf turns
+    every gradient upstream of the SSD into NaN."""
+    cfg = _mamba_cfg(chunk=256)
+    key = jax.random.PRNGKey(4)
+    params = ssm.mamba_init(cfg, key)
+    x = jax.random.normal(jax.random.fold_in(key, 5), (1, 256, cfg.d_model)) * 0.5
+    g = jax.grad(lambda p: jnp.sum(ssm.mamba_apply(cfg, p, x) ** 2))(params)
+    for path, leaf in jax.tree_util.tree_leaves_with_path(g):
+        assert np.all(np.isfinite(np.asarray(leaf))), jax.tree_util.keystr(path)
+
+
 def _rwkv_cfg():
     return ModelConfig(
         arch_id="t", family="ssm", num_layers=1, d_model=64, num_heads=2,
