@@ -194,9 +194,11 @@ def one_chip(args) -> dict:
 
     losses = [e["metrics"]["train/loss"] for e in tracker.events
               if e["kind"] == "metrics" and "train/loss" in e["metrics"]]
-    step_s = [e["seconds"] for e in tracker.events
-              if e["kind"] == "timer" and e["name"] == "train/step"]
-    rounds = [e for e in tracker.events if e["kind"] == "span" and e["name"] == "round"]
+    spans = [e for e in tracker.events if e["kind"] == "span"]
+    rounds = [e for e in spans if e["name"] == "round"]
+    # a step is its jitted call until it returns, then the wait for its outputs
+    step_s = [sum(e["t1"] - e["t0"] for e in spans if e["parent"] == r["span_id"]
+                  and e["name"] in ("dispatch", "wait")) for r in rounds]
     for i, (loss, s, r) in enumerate(zip(losses, step_s, rounds)):
         print(f"step {i}: loss={loss} step_s={s} round_s={r['t1'] - r['t0']} "
               f"full_sync={r['attrs'].get('full_sync')}")

@@ -10,8 +10,6 @@ A tracker receives *events* — normalized dicts with a ``kind``:
               "step": int|None, "wall_time": float}
     span     {"kind": "span", "name": str, "span_id": int, "parent": int|None,
               "t0": float, "t1": float, "attrs": {...}}      (trace.py, §10)
-    profile  {"kind": "profile", "name": str, "trace_dir": str,
-              "wall_time": float}                (jax.profiler provenance)
 
 ``log`` flattens nested dicts with "/" and coerces jax/numpy scalars to
 python floats, so every backend sees the same flat schema. ``row`` is the
@@ -133,31 +131,6 @@ class Tracker:
         from .trace import span as _span
 
         return _span(self, name, **attrs)
-
-    @contextlib.contextmanager
-    def profile(self, name: str, trace_dir: Optional[str] = None):
-        """jax.profiler trace around a block; no-op unless a trace dir is
-        given (or REPRO_OBS_TRACE_DIR is set). When a trace is written, a
-        ``{"kind": "profile", "name", "trace_dir"}`` event records its
-        location, so profiler artifacts are discoverable from the event
-        log instead of silently landing on disk."""
-        trace_dir = trace_dir or os.environ.get("REPRO_OBS_TRACE_DIR")
-        if not trace_dir:
-            yield
-            return
-        import jax
-
-        path = os.path.join(trace_dir, name)
-        with jax.profiler.trace(path):
-            yield
-        self.emit(
-            {
-                "kind": "profile",
-                "name": str(name),
-                "trace_dir": path,
-                "wall_time": time.time(),
-            }
-        )
 
 
 class NullTracker(Tracker):

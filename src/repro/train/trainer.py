@@ -102,14 +102,15 @@ def make_train_step(
     def train_step(state, batch, key, force_sync=False):
         server = state["server"]
         # ---- workers: forward/backward on their own replica -----------------
-        if downlink is None:
-            losses, grads_w = jax.vmap(lambda shard: grad_fn(server, shard))(batch)
-        elif isinstance(downlink, EF21PDownlink):
-            shift = state["workers"]
-            losses, grads_w = jax.vmap(lambda shard: grad_fn(shift, shard))(batch)
-        else:
-            workers = state["workers"]
-            losses, grads_w = jax.vmap(grad_fn)(workers, batch)
+        with jax.named_scope("fwd_bwd"):
+            if downlink is None:
+                losses, grads_w = jax.vmap(lambda shard: grad_fn(server, shard))(batch)
+            elif isinstance(downlink, EF21PDownlink):
+                shift = state["workers"]
+                losses, grads_w = jax.vmap(lambda shard: grad_fn(shift, shard))(batch)
+            else:
+                workers = state["workers"]
+                losses, grads_w = jax.vmap(grad_fn)(workers, batch)
         # ---- uplink: exact aggregation over the round's participants ---------
         # Partial participation (DESIGN.md §8.5/§9.2): the plan maps a
         # participation key to this round's worker mask. Only the uplink
@@ -118,28 +119,30 @@ def make_train_step(
         # (fold_in(key, PARTICIPATION_FOLD)) so the downlink RNG stream is
         # bit-identical to the full-participation path, and every plan draws
         # from the same folded key so swapping plans never perturbs it.
-        if partial:
-            n = tcfg.n_workers
-            k_part = jax.random.fold_in(key, PARTICIPATION_FOLD)
-            participate = plan.mask(k_part, n, state["step"])
-            n_part = jnp.maximum(jnp.sum(participate), 1)
-            w = participate.astype(jnp.float32) / n_part
-            grads = jax.tree.map(
-                lambda g: jnp.tensordot(w, g.astype(jnp.float32), axes=1), grads_w
-            )
-            loss = jnp.sum(w * losses)
-        else:
-            grads = jax.tree.map(
-                lambda g: jnp.mean(g.astype(jnp.float32), axis=0), grads_w
-            )
-            loss = jnp.mean(losses)
-        gnorm_sq = sum(jnp.sum(g * g) for g in jax.tree.leaves(grads))
+        with jax.named_scope("aggregate"):
+            if partial:
+                n = tcfg.n_workers
+                k_part = jax.random.fold_in(key, PARTICIPATION_FOLD)
+                participate = plan.mask(k_part, n, state["step"])
+                n_part = jnp.maximum(jnp.sum(participate), 1)
+                w = participate.astype(jnp.float32) / n_part
+                grads = jax.tree.map(
+                    lambda g: jnp.tensordot(w, g.astype(jnp.float32), axes=1), grads_w
+                )
+                loss = jnp.sum(w * losses)
+            else:
+                grads = jax.tree.map(
+                    lambda g: jnp.mean(g.astype(jnp.float32), axis=0), grads_w
+                )
+                loss = jnp.mean(losses)
+            gnorm_sq = sum(jnp.sum(g * g) for g in jax.tree.leaves(grads))
         # ---- server master update --------------------------------------------
-        if tcfg.polyak_factor > 0:
-            lr = tcfg.polyak_factor * jnp.maximum(loss - tcfg.polyak_f_star, 0.0) / jnp.maximum(gnorm_sq, 1e-20)
-        else:
-            lr = lr_fn(state["step"])
-        server_new, opt_new = optimizer.update(grads, state["opt"], server, lr)
+        with jax.named_scope("optimizer"):
+            if tcfg.polyak_factor > 0:
+                lr = tcfg.polyak_factor * jnp.maximum(loss - tcfg.polyak_f_star, 0.0) / jnp.maximum(gnorm_sq, 1e-20)
+            else:
+                lr = lr_fn(state["step"])
+            server_new, opt_new = optimizer.update(grads, state["opt"], server, lr)
         # ---- uplink: exact dense gradient per worker (w2s, ROADMAP gap) ------
         d = tree_size(server)
         uplink_bits = state["uplink_bits_per_worker"] + CommModel(d=d).dense_bits()
@@ -158,19 +161,23 @@ def make_train_step(
         if downlink is None:
             pass
         elif isinstance(downlink, EF21PDownlink):
-            shift_new, bits = downlink.round(
-                key, server_new, state["workers"], force_sync
-            )
+            with jax.named_scope("downlink"):
+                shift_new, bits = downlink.round(
+                    key, server_new, state["workers"], force_sync
+                )
             new_state["workers"] = shift_new
             new_state["bits_per_worker"] = state["bits_per_worker"] + bits
-            metrics["drift"] = downlink.worker_drift(server_new, shift_new)
+            with jax.named_scope("drift"):
+                metrics["drift"] = downlink.worker_drift(server_new, shift_new)
         else:
-            workers_new, bits = downlink.round(
-                key, server_new, server, state["workers"], force_sync
-            )
+            with jax.named_scope("downlink"):
+                workers_new, bits = downlink.round(
+                    key, server_new, server, state["workers"], force_sync
+                )
             new_state["workers"] = workers_new
             new_state["bits_per_worker"] = state["bits_per_worker"] + bits
-            metrics["drift"] = downlink.worker_drift(server_new, workers_new)
+            with jax.named_scope("drift"):
+                metrics["drift"] = downlink.worker_drift(server_new, workers_new)
         metrics["bits_per_worker"] = new_state["bits_per_worker"]
         return new_state, metrics
 
@@ -194,10 +201,14 @@ def train_loop(
 ):
     """Host loop around the jitted step with per-step telemetry.
 
-    Each step is timed with a ``block_until_ready``-correct host timer
-    ("train/step") and its metrics (loss, grad_norm, lr, drift,
-    bits_per_worker, uplink_bits_per_worker) are logged to ``tracker``
-    at ``log_every`` cadence. Returns (final_state, last_metrics).
+    Each round is one ``round`` span whose children cover it in order:
+    ``feed`` (the batch and the round's key), ``dispatch`` (the step's
+    arguments taken from the state, and the jitted call until it returns),
+    ``wait`` (``block_until_ready`` on the step's outputs), the broadcast
+    spans when a transport is attached, and ``log`` (``tracker.log`` of the
+    metrics — loss, grad_norm, lr, drift, bits_per_worker,
+    uplink_bits_per_worker — at ``log_every`` cadence, with their
+    device-to-host reads). Returns (final_state, last_metrics).
 
     ``transport`` (a :class:`repro.transport.Fleet` or a
     :class:`repro.transport.FaultSpec`) additionally pushes each round's
@@ -235,15 +246,19 @@ def train_loop(
     m = {}
     force_sync = False
     for i in range(steps):
-        batch = data.batch(i)
-        k_step = jax.random.fold_in(k_steps, i)
-        prev = state[kept]
-        rest = {k: v for k, v in state.items() if k != kept}
         was_forced = force_sync
         with span(tracker, "round", round=i, alg="train") as rsp:
-            with tracker.time_block("train/step", step=i) as tb:
+            with span(tracker, "feed"):
+                batch = data.batch(i)
+                k_step = jax.random.fold_in(k_steps, i)
+            with span(tracker, "dispatch"):
+                # rebinding prev frees the last round's kept tree: host
+                # time that belongs to preparing the call
+                prev = state[kept]
+                rest = {k: v for k, v in state.items() if k != kept}
                 state, m = step(prev, rest, batch, k_step, force_sync)
-                tb.block(m)
+            with span(tracker, "wait"):
+                jax.block_until_ready(m)
             if fleet is not None:
                 res = downlink.broadcast_via(
                     fleet, k_step, state["server"], prev,
@@ -253,9 +268,10 @@ def train_loop(
                 force_sync = res["resync_needed"]
                 maybe_attr(rsp, full_sync=res["full_sync"],
                            resync_next=force_sync)
-            maybe_attr(rsp, force_sync=was_forced, loss=float(m["loss"]))
-        if i % log_every == 0:
-            tracker.log({"train": m}, step=i)
+            maybe_attr(rsp, force_sync=was_forced)
+            if i % log_every == 0:
+                with span(tracker, "log"):
+                    tracker.log({"train": m}, step=i)
     if fleet is not None:
         m = dict(m)
         m["transport"] = fleet.stats().as_metrics()

@@ -259,30 +259,50 @@ def test_bench_sink_aggregates_spans_as_namespaced_timers(tmp_path):
     assert bench_json.validate(doc) == []
 
 
-# -- profile event ------------------------------------------------------------
+# -- profiler annotations -----------------------------------------------------
 
 
-def test_profile_emits_trace_dir_event(tmp_path):
+def test_spans_are_annotations_on_the_profiler_clock(tmp_path):
+    """Under a jax.profiler trace every span has its prefixed annotation in
+    the host plane, nested as the spans are and as long to within 0.1 ms."""
+    import glob
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    from repro.obs.trace import ANNOTATION_PREFIX
+
     tr = obs.MemoryTracker()
-    with tr.profile("step", trace_dir=str(tmp_path)):
-        import jax.numpy as jnp
-
-        jnp.ones(4).block_until_ready()
-    profs = [e for e in tr.events if e["kind"] == "profile"]
-    assert len(profs) == 1
-    assert profs[0]["name"] == "step"
-    assert profs[0]["trace_dir"] == os.path.join(str(tmp_path), "step")
-    assert os.path.isdir(profs[0]["trace_dir"])
-    # no trace dir configured -> no-op, no event
-    tr2 = obs.MemoryTracker()
-    env = os.environ.pop("REPRO_OBS_TRACE_DIR", None)
-    try:
-        with tr2.profile("step"):
-            pass
-    finally:
-        if env is not None:
-            os.environ["REPRO_OBS_TRACE_DIR"] = env
-    assert tr2.events == []
+    with jax.profiler.trace(str(tmp_path)):
+        for t in range(3):
+            with tr.span("round", round=t):
+                with tr.span("feed"):
+                    time.sleep(0.002)
+                with tr.span("dispatch"):
+                    x = jnp.ones(64) * t
+                with tr.span("wait"):
+                    x.block_until_ready()
+                with tr.span("log"):
+                    with tr.span("link/w0/retry"):
+                        pass
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = sorted(((e.name, e.start_ns, e.start_ns + e.duration_ns)
+                   for plane in ProfileData.from_file(path).planes
+                   if plane.name == "/host:CPU"
+                   for line in plane.lines for e in line.events
+                   if e.name.startswith(ANNOTATION_PREFIX)),
+                  key=lambda h: (h[1], -h[2]))
+    spans = sorted((e for e in tr.events if e["kind"] == "span"), key=lambda e: e["t0"])
+    assert len(spans) == 3 * 6
+    assert [n for n, _, _ in host] == [ANNOTATION_PREFIX + s["name"] for s in spans]
+    by_id = {s["span_id"]: iv for s, iv in zip(spans, host)}
+    for s, (_, a, b) in zip(spans, host):
+        assert abs((b - a) / 1e9 - (s["t1"] - s["t0"])) < 1e-4, s["name"]
+        if s["parent"] is not None:
+            _, pa, pb = by_id[s["parent"]]
+            assert pa <= a and b <= pb, s["name"]
 
 
 # -- fleet cohort spans -------------------------------------------------------

@@ -166,7 +166,8 @@ def test_serve_engine_latency_telemetry(small):
 
 
 def test_train_loop_tracker_and_uplink_bits(small):
-    """train_loop logs step timers + metrics; uplink accrues dense bits/step."""
+    """train_loop covers each round with feed/dispatch/wait/log spans and
+    logs its metrics; uplink accrues dense bits/step."""
     import math
 
     from repro import obs
@@ -182,9 +183,18 @@ def test_train_loop_tracker_and_uplink_bits(small):
         cfg, tcfg, dl, make_optimizer("adamw"), constant_lr(2e-3), data,
         steps=3, key=jax.random.PRNGKey(0), tracker=tr,
     )
-    timers = [e for e in tr.events if e["kind"] == "timer"]
-    assert [t["name"] for t in timers] == ["train/step"] * 3
-    assert all(t["seconds"] > 0 for t in timers)
+    spans = [e for e in tr.events if e["kind"] == "span"]
+    rounds = [s for s in spans if s["name"] == "round"]
+    assert [r["attrs"]["round"] for r in rounds] == [0, 1, 2]
+    for r in rounds:
+        assert r["parent"] is None and "loss" not in r["attrs"]
+        kids = sorted((s for s in spans if s["parent"] == r["span_id"]),
+                      key=lambda s: s["t0"])
+        assert [k["name"] for k in kids] == ["feed", "dispatch", "wait", "log"]
+        edges = [r["t0"]] + [t for k in kids for t in (k["t0"], k["t1"])] + [r["t1"]]
+        assert edges == sorted(edges)
+        assert all(k["t1"] > k["t0"] for k in kids)
+    assert not [e for e in tr.events if e["kind"] == "timer"]
     mets = [e for e in tr.events if e["kind"] == "metrics"]
     d = tree_size(state["server"])
     # uplink = one exact dense (64-bit model) gradient per worker per step
@@ -193,6 +203,25 @@ def test_train_loop_tracker_and_uplink_bits(small):
         float(m["uplink_bits_per_worker"]), rel=1e-6
     )
     assert "train/loss" in mets[0]["metrics"]
+
+
+@pytest.mark.parametrize("spec", ["marina:perm", "ef21p:16:64"])
+def test_step_ops_carry_named_scopes(small, spec):
+    """The compiled step's ops name the phase of the round that made them."""
+    import re
+
+    cfg, tcfg = small
+    dl = make_downlink(spec, tcfg.n_workers)
+    opt = make_optimizer("adamw")
+    state = init_state(cfg, tcfg, dl, opt, jax.random.PRNGKey(0))
+    step = jax.jit(make_train_step(cfg, tcfg, dl, opt, constant_lr(2e-3)))
+    batch = SyntheticLMData(cfg, tcfg.n_workers, 2, 64).batch(0)
+    hlo = step.lower(state, batch, jax.random.PRNGKey(1)).compile().as_text()
+    op_names = re.findall(r'op_name="([^"]*)"', hlo)
+    for scope in ("fwd_bwd", "aggregate", "optimizer", "downlink", "drift"):
+        assert any(f"/{scope}/" in n for n in op_names), scope
+    # backward ops are the transposes of the forward pass, inside fwd_bwd
+    assert any("/fwd_bwd/" in n and "transpose(" in n for n in op_names)
 
 
 def test_lr_schedules():
