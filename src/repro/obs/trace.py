@@ -39,8 +39,13 @@ Instrumented paths emit this vocabulary (see DESIGN.md §10.2):
       feed                train_loop: the next batch and the round's key
       dispatch            train_loop: the step's arguments and its jitted call,
                           until the call returns
-      wait                train_loop: block_until_ready on the step's outputs
+      wait                train_loop: block_until_ready on a step's outputs;
+                          of_round (the round read: the one before when no
+                          transport is attached, this one with a transport),
+                          ahead (rounds queued behind it: 1, or 0)
       log                 train_loop: tracker.log and its device-to-host reads
+                          (of_round, as wait); the last round's wait and log
+                          follow the last round span, at top level
       subgrad             the jitted step (subgrad + stepsize + compress, fused)
       stepsize            host read of gamma (attrs carry the reacted value)
       broadcast           downlink delivery section

@@ -14,6 +14,7 @@ Polyak adaptive LR (the paper's (13), with f* estimate) is available as
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 from typing import Any, Callable, Dict, Optional
 
@@ -204,11 +205,22 @@ def train_loop(
     Each round is one ``round`` span whose children cover it in order:
     ``feed`` (the batch and the round's key), ``dispatch`` (the step's
     arguments taken from the state, and the jitted call until it returns),
-    ``wait`` (``block_until_ready`` on the step's outputs), the broadcast
+    ``wait`` (``block_until_ready`` on a step's outputs), the broadcast
     spans when a transport is attached, and ``log`` (``tracker.log`` of the
     metrics — loss, grad_norm, lr, drift, bits_per_worker,
     uplink_bits_per_worker — at ``log_every`` cadence, with their
-    device-to-host reads). Returns (final_state, last_metrics).
+    device-to-host reads). ``wait`` and ``log`` carry ``of_round``, the
+    round they read; ``wait`` also carries ``ahead``, the number of rounds
+    enqueued behind that one. Returns (final_state, last_metrics).
+
+    With no transport attached nothing the host does after a round feeds
+    the next, so the loop reads each round one iteration late: round i's
+    ``wait`` and ``log`` read round i-1 (``ahead`` 1) while round i is
+    queued on the device behind it, and the host's work hides under the
+    device's. The last dispatched round is read after the loop, however the
+    loop ends (also when ``data.batch`` raises), as top-level ``wait`` and
+    ``log`` spans. At ``data.batch(i)`` the loop's ``state`` holds the state
+    after round i-1, dispatched but not necessarily finished.
 
     ``transport`` (a :class:`repro.transport.Fleet` or a
     :class:`repro.transport.FaultSpec`) additionally pushes each round's
@@ -216,8 +228,9 @@ def train_loop(
     ``broadcast_via``; a round whose delivery degrades (undelivered
     worker or receiver resync request) promotes the *next* round's
     broadcast to a full sync, whose self-contained SYNC frame repairs
-    every receiver (DESIGN.md §8.4). The last metrics dict then carries
-    the fleet counters under ``"transport"``.
+    every receiver (DESIGN.md §8.4). So each round is waited on, broadcast
+    and logged before the next is dispatched (``ahead`` 0). The last
+    metrics dict then carries the fleet counters under ``"transport"``.
     """
     from repro import obs
 
@@ -225,15 +238,6 @@ def train_loop(
     k_init, k_steps = jax.random.split(key)
     state = init_state(cfg, tcfg, downlink, optimizer, k_init)
     train_step = make_train_step(cfg, tcfg, downlink, optimizer, lr_fn)
-    # The broadcast reads the old server (MARINA-P) or the old shift
-    # (EF21-P) after the step. Every other buffer of the state is donated,
-    # so old and new optimizer moments and replicas are never resident
-    # together (at 326M parameters they would not fit one 16 GB chip).
-    kept = "workers" if isinstance(downlink, EF21PDownlink) else "server"
-    step = jax.jit(
-        lambda prev, rest, batch, key, fs: train_step({kept: prev, **rest}, batch, key, fs),
-        donate_argnums=1,
-    )
     fleet = None
     if transport is not None and downlink is not None:
         from repro.transport import FaultSpec, Fleet
@@ -243,35 +247,72 @@ def train_loop(
             if isinstance(transport, FaultSpec)
             else transport
         )
+    # A transport's delivery of a round decides the next round's
+    # force_sync, so the next round waits for it; otherwise one round stays
+    # queued behind the one the host reads.
+    ahead = 0 if fleet is not None else 1
+    # The broadcast reads the old server (MARINA-P) or the old shift
+    # (EF21-P) after the step, so with a transport that tree is kept. Every
+    # other buffer of the state is donated, so old and new optimizer moments
+    # and replicas are never resident together (at 326M parameters they
+    # would not fit one 16 GB chip). Without a transport the kept tree is
+    # donated too: the queued step's outputs then take its place, where a
+    # fresh tree would wait for the running step to free it and raise the
+    # peak. The price is one tree copied inside the step, since the old
+    # server is read after the new one is written.
+    kept = "workers" if isinstance(downlink, EF21PDownlink) else "server"
+    step = jax.jit(
+        lambda prev, rest, batch, key, fs: train_step({kept: prev, **rest}, batch, key, fs),
+        donate_argnums=1 if fleet is not None else (0, 1),
+    )
+    unread = collections.deque()  # (round, metrics): dispatched, not yet read
+
+    def wait(j, m_j):
+        with span(tracker, "wait", of_round=j, ahead=len(unread)):
+            jax.block_until_ready(m_j)
+
+    def log(j, m_j):
+        if j % log_every == 0:
+            with span(tracker, "log", of_round=j):
+                tracker.log({"train": m_j}, step=j)
+
     m = {}
     force_sync = False
-    for i in range(steps):
-        was_forced = force_sync
-        with span(tracker, "round", round=i, alg="train") as rsp:
-            with span(tracker, "feed"):
-                batch = data.batch(i)
-                k_step = jax.random.fold_in(k_steps, i)
-            with span(tracker, "dispatch"):
-                # rebinding prev frees the last round's kept tree: host
-                # time that belongs to preparing the call
-                prev = state[kept]
-                rest = {k: v for k, v in state.items() if k != kept}
-                state, m = step(prev, rest, batch, k_step, force_sync)
-            with span(tracker, "wait"):
-                jax.block_until_ready(m)
-            if fleet is not None:
-                res = downlink.broadcast_via(
-                    fleet, k_step, state["server"], prev,
-                    mag=wire_mag, force_sync=force_sync, tracker=tracker,
-                    step=i,
-                )
-                force_sync = res["resync_needed"]
-                maybe_attr(rsp, full_sync=res["full_sync"],
-                           resync_next=force_sync)
-            maybe_attr(rsp, force_sync=was_forced)
-            if i % log_every == 0:
-                with span(tracker, "log"):
-                    tracker.log({"train": m}, step=i)
+    try:
+        for i in range(steps):
+            was_forced = force_sync
+            with span(tracker, "round", round=i, alg="train") as rsp:
+                with span(tracker, "feed"):
+                    batch = data.batch(i)
+                    k_step = jax.random.fold_in(k_steps, i)
+                with span(tracker, "dispatch"):
+                    # rebinding prev frees the last round's kept tree: host
+                    # time that belongs to preparing the call
+                    prev = state[kept]
+                    rest = {k: v for k, v in state.items() if k != kept}
+                    state, m = step(prev, rest, batch, k_step, force_sync)
+                unread.append((i, m))
+                if len(unread) > ahead:
+                    j, m_j = unread.popleft()
+                    wait(j, m_j)
+                    if fleet is not None:  # ahead 0: j is this round
+                        res = downlink.broadcast_via(
+                            fleet, k_step, state["server"], prev,
+                            mag=wire_mag, force_sync=force_sync, tracker=tracker,
+                            step=i,
+                        )
+                        force_sync = res["resync_needed"]
+                        maybe_attr(rsp, full_sync=res["full_sync"],
+                                   resync_next=force_sync)
+                    log(j, m_j)
+                maybe_attr(rsp, force_sync=was_forced)
+    finally:
+        # every dispatched round is read, also when the loop is left by an
+        # exception (a data source that ends the run from data.batch)
+        while unread:
+            j, m_j = unread.popleft()
+            wait(j, m_j)
+            log(j, m_j)
     if fleet is not None:
         m = dict(m)
         m["transport"] = fleet.stats().as_metrics()

@@ -166,10 +166,10 @@ def test_serve_engine_latency_telemetry(small):
 
 
 def test_train_loop_tracker_and_uplink_bits(small):
-    """train_loop covers each round with feed/dispatch/wait/log spans and
-    logs its metrics; uplink accrues dense bits/step."""
-    import math
-
+    """train_loop covers each round with feed/dispatch spans, then waits on
+    and logs the round before it (wait/log, ``of_round`` = round - 1) while
+    this one is queued; the last round is read after the loop. Metrics are
+    logged; uplink accrues dense bits/step."""
     from repro import obs
     from repro.data import SyntheticLMData
     from repro.optim import make_optimizer
@@ -190,12 +190,20 @@ def test_train_loop_tracker_and_uplink_bits(small):
         assert r["parent"] is None and "loss" not in r["attrs"]
         kids = sorted((s for s in spans if s["parent"] == r["span_id"]),
                       key=lambda s: s["t0"])
-        assert [k["name"] for k in kids] == ["feed", "dispatch", "wait", "log"]
+        want = ["feed", "dispatch"] + (["wait", "log"] if r["attrs"]["round"] else [])
+        assert [k["name"] for k in kids] == want
+        for k in kids[2:]:
+            assert k["attrs"]["of_round"] == r["attrs"]["round"] - 1
         edges = [r["t0"]] + [t for k in kids for t in (k["t0"], k["t1"])] + [r["t1"]]
         assert edges == sorted(edges)
         assert all(k["t1"] > k["t0"] for k in kids)
+    # the drained last round: top-level wait and log after the last round span
+    drained = [s for s in spans if s["parent"] is None and s["name"] != "round"]
+    assert [s["name"] for s in drained] == ["wait", "log"]
+    assert all(s["attrs"]["of_round"] == 2 and s["t0"] >= rounds[-1]["t1"] for s in drained)
     assert not [e for e in tr.events if e["kind"] == "timer"]
     mets = [e for e in tr.events if e["kind"] == "metrics"]
+    assert [e["step"] for e in mets] == [0, 1, 2]
     d = tree_size(state["server"])
     # uplink = one exact dense (64-bit model) gradient per worker per step
     assert float(m["uplink_bits_per_worker"]) == pytest.approx(3 * 64.0 * d, rel=1e-6)
@@ -203,6 +211,134 @@ def test_train_loop_tracker_and_uplink_bits(small):
         float(m["uplink_bits_per_worker"]), rel=1e-6
     )
     assert "train/loss" in mets[0]["metrics"]
+
+
+def _loop(small, spec, data, steps, tracker, **kw):
+    from repro.train import train_loop
+
+    cfg, tcfg = small
+    return train_loop(
+        cfg, tcfg, make_downlink(spec, tcfg.n_workers), make_optimizer("adamw"),
+        constant_lr(2e-3), data, steps=steps, key=jax.random.PRNGKey(5),
+        tracker=tracker, **kw,
+    )
+
+
+@pytest.mark.parametrize("spec", ["marina:perm", "ef21p:16:64", "none"])
+def test_train_loop_matches_the_step_round_by_round(small, spec):
+    """Running one round ahead changes no number: the final state and each
+    round's logged metrics are bit-identical to the jitted step called round
+    by round on the same batches and keys."""
+    from repro import obs
+
+    cfg, tcfg = small
+    steps = 4
+    data = SyntheticLMData(cfg, tcfg.n_workers, 2, 64)
+    tr = obs.MemoryTracker()
+    state, _ = _loop(small, spec, data, steps, tr)
+
+    dl = make_downlink(spec, tcfg.n_workers)
+    opt = make_optimizer("adamw")
+    k_init, k_steps = jax.random.split(jax.random.PRNGKey(5))
+    ref = init_state(cfg, tcfg, dl, opt, k_init)
+    step = jax.jit(make_train_step(cfg, tcfg, dl, opt, constant_lr(2e-3)))
+    ref_logs = []
+    for i in range(steps):
+        ref, m = step(ref, data.batch(i), jax.random.fold_in(k_steps, i))
+        ref_logs.append((i, {f"train/{k}": float(v) for k, v in m.items()}))
+
+    logs = [(e["step"], e["metrics"]) for e in tr.events if e["kind"] == "metrics"]
+    assert logs == ref_logs
+    keys = set(logs[0][1])
+    assert {"train/loss", "train/grad_norm", "train/bits_per_worker"} <= keys
+    assert ("train/drift" in keys) == (spec != "none")
+    assert jax.tree.structure(state) == jax.tree.structure(ref)
+    for a, b in zip(jax.tree.leaves(state), jax.tree.leaves(ref)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+class _StopAt:
+    """A data source that ends the run by raising from ``batch(stop)``, and
+    checks at each ``batch(i)`` that its caller's frame holds the loop's
+    state after round i-1 in exactly one local dict."""
+
+    class Stop(Exception):
+        pass
+
+    def __init__(self, data, stop):
+        self.data, self.stop, self.steps_seen = data, stop, []
+
+    def batch(self, i):
+        import sys
+
+        found = [v for v in sys._getframe(1).f_locals.values()
+                 if isinstance(v, dict) and {"server", "opt", "workers"} <= v.keys()]
+        assert len(found) == 1
+        self.steps_seen.append(int(found[0]["step"]))
+        if i == self.stop:
+            raise self.Stop
+        return self.data.batch(i)
+
+
+def test_train_loop_left_from_the_data_source_logs_every_round(small):
+    """A data source that raises at batch(k) leaves the loop with rounds 0
+    to k-1 all logged, each under its own step, and the exception raised;
+    at each batch(i) the caller's frame holds the state whose step is i."""
+    from repro import obs
+
+    cfg, tcfg = small
+    src = _StopAt(SyntheticLMData(cfg, tcfg.n_workers, 2, 64), stop=3)
+    tr = obs.MemoryTracker()
+    with pytest.raises(_StopAt.Stop):
+        _loop(small, "marina:perm", src, 10, tr)
+    assert src.steps_seen == [0, 1, 2, 3]
+    mets = [e for e in tr.events if e["kind"] == "metrics"]
+    assert [e["step"] for e in mets] == [0, 1, 2]
+    assert all(np.isfinite(e["metrics"]["train/loss"]) for e in mets)
+
+
+def test_train_loop_waits_with_the_next_round_queued(small):
+    """With no transport every wait inside a round reads the round before,
+    with one round queued behind it (``ahead`` 1); the drained last round
+    has none behind it."""
+    from repro import obs
+
+    cfg, tcfg = small
+    tr = obs.MemoryTracker()
+    _loop(small, "marina:perm", SyntheticLMData(cfg, tcfg.n_workers, 2, 64), 4, tr)
+    spans = [e for e in tr.events if e["kind"] == "span"]
+    round_of = {s["span_id"]: s["attrs"]["round"] for s in spans if s["name"] == "round"}
+    waits = [s for s in spans if s["name"] == "wait"]
+    inside = [s for s in waits if s["parent"] is not None]
+    assert [round_of[s["parent"]] for s in inside] == [1, 2, 3]
+    assert all(s["attrs"]["ahead"] == 1 for s in inside)
+    assert [(s["attrs"]["of_round"], s["attrs"]["ahead"]) for s in waits if s["parent"] is None] \
+        == [(3, 0)]
+
+
+def test_train_loop_with_a_transport_reads_each_round_before_the_next(small):
+    """With a transport attached, each round is fed, dispatched, waited on
+    (``ahead`` 0), broadcast and logged in that order, before the next."""
+    from repro import obs
+    from repro.transport import FaultSpec
+
+    cfg, tcfg = small
+    tr = obs.MemoryTracker()
+    _loop(small, "marina:perm", SyntheticLMData(cfg, tcfg.n_workers, 2, 64), 3, tr,
+          transport=FaultSpec())
+    spans = [e for e in tr.events if e["kind"] == "span"]
+    rounds = [s for s in spans if s["name"] == "round"]
+    assert [r["attrs"]["round"] for r in rounds] == [0, 1, 2]
+    assert [s for s in spans if s["parent"] is None] == rounds
+    for r in rounds:
+        kids = sorted((s for s in spans if s["parent"] == r["span_id"]),
+                      key=lambda s: s["t0"])
+        assert [k["name"] for k in kids] == ["feed", "dispatch", "wait", "broadcast", "log"]
+        assert kids[2]["attrs"] == {"of_round": r["attrs"]["round"], "ahead": 0}
+        assert kids[4]["attrs"] == {"of_round": r["attrs"]["round"]}
+        assert "full_sync" in r["attrs"] and "resync_next" in r["attrs"]
+    mets = [e for e in tr.events if e["kind"] == "metrics" and "train/loss" in e["metrics"]]
+    assert [e["step"] for e in mets] == [0, 1, 2]
 
 
 @pytest.mark.parametrize("spec", ["marina:perm", "ef21p:16:64"])
