@@ -1,12 +1,13 @@
-"""Plain float32 reference of the zamba2 configuration and of its training
-round: the forward pass and loss, the gradient, AdamW, and the server-to-
-worker broadcast (MARINA-P with the RotK partition). It imports nothing of
+"""Plain float32 reference of the zamba2 configuration: its weights from
+the seed, its forward pass and loss, and its FLOP count. The training
+round around it is ``bench/lib/reference_train.py``. It imports nothing of
 the program.
 
-Everything is computed in float32 with ``highest`` matmul precision; the
-Mamba2 state is carried by the plain recurrence over positions
-(h_t = exp(dt_t A) h_{t-1} + dt_t B_t x_t^T, y_t = C_t h_t + D x_t), not by
-chunks. Attention is softmax over the full causal score matrix.
+Everything is computed in float32 (the round sets ``highest`` matmul
+precision); the Mamba2 state is carried by the plain recurrence over
+positions (h_t = exp(dt_t A) h_{t-1} + dt_t B_t x_t^T, y_t = C_t h_t +
+D x_t), not by chunks. Attention is softmax over the full causal score
+matrix.
 
 Weights are made from the seed by the draws that the configuration's
 initialiser defines, in its layout: the leading run of Mamba2 layers is
@@ -14,19 +15,13 @@ stacked on a leading axis (``stack/segments/0/b0``), the shared block has
 one set of weights (``stack/shared_blk``), and each Mamba2 layer after it
 has a segment of its own. The leaf order of that layout also orders the
 broadcast's per-leaf randomness.
-
-``matmul="fp8"`` rounds both operands of every projection, attention and
-output-head matmul of the forward pass to float8 e4m3 with a per-tensor
-scale (gradients pass straight through): the control that computes in the
-precision below the configuration's bfloat16.
 """
 from __future__ import annotations
 
-from typing import Dict, List
-
 import jax
 import jax.numpy as jnp
-import numpy as np
+
+from bench.lib import counts
 
 F32 = jnp.float32
 REC_STRETCH = 64
@@ -118,18 +113,11 @@ def init_params(cfg, key):
 # ---------------------------------------------------------------------------
 
 
-def _fp8(x):
-    """x rounded to float8 e4m3 under a per-tensor scale; identity gradient."""
-    s = 448.0 / jnp.maximum(jnp.max(jnp.abs(x)), 1e-30)
-    q = (x * s).astype(jnp.float8_e4m3fn).astype(F32) / s
-    return x + jax.lax.stop_gradient(q - x)
-
-
 def _rmsnorm(scale, x, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * scale
 
 
-def _forward_loss(cfg, params, tokens, mm):
+def forward_loss(cfg, params, tokens, mm):
     """Mean next-token cross entropy of one sequence ``tokens`` [S]."""
     D = cfg["d_model"]
     eps = cfg["rms_eps"]
@@ -217,124 +205,20 @@ def _forward_loss(cfg, params, tokens, mm):
     return jnp.mean(lse - picked)
 
 
-def _matmul(kind: str):
-    if kind == "f32":
-        return lambda a, b: jnp.matmul(a, b, precision="highest")
-    if kind == "fp8":
-        return lambda a, b: jnp.matmul(_fp8(a), _fp8(b), precision="highest")
-    raise ValueError(kind)
-
-
 # ---------------------------------------------------------------------------
-# one training round
+# operations a token needs
 # ---------------------------------------------------------------------------
 
 
-def _adamw(t, g, m, v, count):
-    count = count + 1
-    m = jax.tree.map(lambda m_, g_: t["b1"] * m_ + (1 - t["b1"]) * g_, m, g)
-    v = jax.tree.map(lambda v_, g_: t["b2"] * v_ + (1 - t["b2"]) * g_ * g_, v, g)
-    c1 = 1 - t["b1"] ** count.astype(F32)
-    c2 = 1 - t["b2"] ** count.astype(F32)
-    return m, v, count, lambda p, m_, v_: p - t["lr"] * (
-        (m_ / c1) / (jnp.sqrt(v_ / c2) + t["eps"]) + t["weight_decay"] * p)
-
-
-def _marina_perm(key, x_new, x_old, replicas, n):
-    """Algorithm 2's broadcast, RotK partition: with probability 1/n every
-    replica becomes x_new; otherwise worker w adds n times the entries j of
-    each leaf's change with j mod n == (w + r) mod n, r drawn per leaf."""
-    k_bern, k_comp = jax.random.split(key)
-    sync = jax.random.bernoulli(k_bern, 1.0 / n)
-    out = []
-    for li, (xn, xo, wr) in enumerate(zip(jax.tree.leaves(x_new), jax.tree.leaves(x_old),
-                                          jax.tree.leaves(replicas))):
-        r = jax.random.randint(jax.random.fold_in(k_comp, li), (), 0, n)
-        j = jnp.arange(xn.size) % n
-        masks = jnp.stack([(j == (w + r) % n).reshape(xn.shape) for w in range(n)])
-        upd = wr + jnp.where(masks, (xn - xo)[None] * n, 0.0)
-        out.append(jnp.where(sync, jnp.broadcast_to(xn[None], wr.shape), upd))
-    return jax.tree.unflatten(jax.tree.structure(replicas), out)
-
-
-def make_round(cfg, traffic, matmul: str = "f32", fault: str = ""):
-    """``round(state, tokens [W, B, S], key) -> (state, loss, grad_leaf_norms)``
-    under the MARINA-P perm downlink (the only one the reference covers).
-    ``state`` is ``{"server", "m", "v", "count", "workers"}``; ``workers`` has a
-    leading worker axis.
-
-    ``fault`` plants one of the faults the comparison must catch, for the
-    control's readings: ``half_batch`` (every worker trains on worker 0's
-    rows, so the mean is over half the batch) or ``altered`` (the final
-    norm's scale moved by 1e-2 after the update)."""
-    t = cfg["train"]
-    n = t["workers"]
-    mm = _matmul(matmul)
-    if traffic["downlink"] != "marina:perm":
-        raise ValueError(f"the reference covers marina:perm, not {traffic['downlink']}")
-    loss_grad = jax.value_and_grad(
-        lambda p, toks: jnp.mean(jnp.stack([_forward_loss(cfg, p, s, mm) for s in toks])))
-
-    def rnd(state, tokens, key):
-        with jax.default_matmul_precision("highest"):
-            g_sum, losses = None, []
-            for w in range(n):
-                at = jax.tree.map(lambda r: r[w], state["workers"])
-                loss, g = loss_grad(at, tokens[0 if fault == "half_batch" else w])
-                losses.append(loss)
-                g_sum = g if g_sum is None else jax.tree.map(jnp.add, g_sum, g)
-            g = jax.tree.map(lambda s: s / n, g_sum)
-            m, v, count, upd = _adamw(t, g, state["m"], state["v"], state["count"])
-            x_new = jax.tree.map(upd, state["server"], m, v)
-            if fault == "altered":
-                x_new = dict(x_new, ln_f={"scale": x_new["ln_f"]["scale"] + 1e-2})
-            workers = _marina_perm(key, x_new, state["server"], state["workers"], n)
-            gnorms = [jnp.sqrt(jnp.sum(l * l)) for l in jax.tree.leaves(g)]
-        new = {"server": x_new, "m": m, "v": v, "count": count, "workers": workers}
-        return new, jnp.mean(jnp.stack(losses)), gnorms
-
-    return jax.jit(rnd, donate_argnums=0)
-
-
-def init_state(cfg, traffic, key):
-    x0 = init_params(cfg, key)
-    zeros = jax.tree.map(jnp.zeros_like, x0)
-    n = cfg["train"]["workers"]
-    workers = jax.tree.map(lambda t: jnp.broadcast_to(t[None], (n,) + t.shape), x0)
-    return {"server": x0, "m": zeros, "v": jax.tree.map(jnp.copy, zeros),
-            "count": jnp.zeros((), jnp.int32), "workers": workers}
-
-
-def leaf_change_norms(tree, x0) -> List[float]:
-    """Per leaf, the norm of ``tree - x0``, summed in float64 on the host."""
-    return [float(np.sqrt(np.sum((np.asarray(a, np.float64) - np.asarray(b, np.float64)) ** 2)))
-            for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves(x0))]
-
-
-def worker_trees(workers, n: int) -> List:
-    """The workers' replicas one by one."""
-    return [jax.tree.map(lambda t: t[w], workers) for w in range(n)]
-
-
-def run(cfg, traffic, tokens: List, key_init, step_keys: List, matmul: str = "f32",
-        fault: str = "") -> Dict:
-    """The reference's readings over ``len(step_keys)`` rounds from the
-    seed: each round's loss, the first gradient's leaf norms, AdamW's first
-    moment after the first round (``m1``, leaf by leaf on the host: (1 - b1)
-    times the first gradient), and the leaf norms of the server's and of
-    each worker's change over all the rounds."""
-    rnd = make_round(cfg, traffic, matmul, fault)
-    state = init_state(cfg, traffic, key_init)
-    losses, gnorms, m1 = [], None, None
-    for i, k in enumerate(step_keys):
-        state, loss, g = rnd(state, tokens[i], k)
-        losses.append(float(loss))
-        if i == 0:
-            gnorms = [float(x) for x in g]
-            m1 = [np.asarray(l) for l in jax.tree.leaves(jax.device_get(state["m"]))]
-    server, workers = jax.device_get((state["server"], state["workers"]))
-    del state
-    x0 = jax.device_get(init_params(cfg, key_init))
-    return {"loss": losses, "grad": gnorms, "m1": m1,
-            "change": [leaf_change_norms(server, x0)]
-            + [leaf_change_norms(w, x0) for w in worker_trees(workers, cfg["train"]["workers"])]}
+def forward_flops_per_token(cfg, seq_len: int) -> float:
+    """Forward FLOPs a token (``bench/lib/counts.py``): each Mamba2 layer,
+    the shared block's attention and gated MLP where it sits in the
+    pattern, and the output head."""
+    D, m = cfg["d_model"], cfg["mamba"]
+    k_mamba, j_mamba = _layout(cfg)
+    mamba = counts.mamba2_layer_flops(D, m["state_dim"], m["head_dim"], m["expand"],
+                                      m["conv_width"])
+    shared = (counts.attention_flops(D, cfg["num_heads"], cfg["num_kv_heads"], cfg["head_dim"],
+                                     seq_len)
+              + counts.gated_mlp_flops(D, cfg["d_ff"]))
+    return (k_mamba + j_mamba) * mamba + shared + counts.output_head_flops(D, cfg["vocab_size"])

@@ -22,6 +22,27 @@ Traffic parameters: ``downlink`` (the program's downlink spec),
 ``batch_per_worker``, ``seq_len``, ``checked_rounds``, ``pool`` (distinct
 batches made at set-up; a window that outlasts them cycles), and
 ``trace_rounds``.
+
+The driver knows no model. A training configuration brings, as files of
+its own:
+
+    bench/configs/<config>.json   the program's ``ModelConfig`` fields (each
+                                  sub-configuration a dict, built into the
+                                  dataclass that ``ModelConfig``'s type hint
+                                  names, e.g. ``Optional[MambaConfig]``), its
+                                  ``train`` settings and ``reference``, the
+                                  name of its reference module
+    bench/configs/<model>_reference.py
+                                  ``init_params(cfg, key)``,
+                                  ``forward_loss(cfg, params, tokens, mm)``
+                                  (the model that ``bench/lib/reference_train``
+                                  trains) and ``forward_flops_per_token(cfg,
+                                  seq_len)`` (from ``bench/lib/counts``), read
+                                  at set-up on every run
+    bench/limits/<workload>.json  the limits of the numbers compared
+    bench/traffic/<mix>.json      only where the mix is new
+
+and its entries in ``BENCHMARK.json``: the configuration and its cells.
 """
 from __future__ import annotations
 
@@ -31,12 +52,13 @@ import importlib.util
 import math
 import sys
 import time
+import typing
 from typing import Dict, List
 
 import numpy as np
 from repro.obs import Tracker
 
-from .. import counts
+from .. import counts, reference_train
 from ..compare import train_checks
 from ..harness import BENCH, GcWatch, Outcome, TraceWindow, memory_peak_bytes, seed_key
 from ..peaks import peaks
@@ -47,22 +69,42 @@ class WindowClosed(Exception):
     """Raised from the feed to leave the program's loop at the window's end."""
 
 
-def model_config(cfg: dict):
-    """The program's ModelConfig from the configuration file."""
-    from repro.models.config import MambaConfig, ModelConfig
+def _from_json(hint, value):
+    """``value``, as read from JSON, in the type ``hint`` names: a dataclass
+    built from its dict, field by field (keys the dict lacks keep their
+    defaults), a tuple from its list, anything else as it is."""
+    if value is None:
+        return None
+    if typing.get_origin(hint) is typing.Union:  # Optional[X]
+        (hint,) = [a for a in typing.get_args(hint) if a is not type(None)]
+    if dataclasses.is_dataclass(hint):
+        hints = typing.get_type_hints(hint)
+        return hint(**{f.name: _from_json(hints[f.name], value[f.name])
+                       for f in dataclasses.fields(hint) if f.name in value})
+    return tuple(value) if typing.get_origin(hint) is tuple else value
 
-    names = {f.name for f in dataclasses.fields(ModelConfig)}
-    kw = {k: v for k, v in cfg.items() if k in names}
-    kw["block_pattern"] = tuple(kw["block_pattern"])
-    kw["mamba"] = MambaConfig(**kw["mamba"])
-    return ModelConfig(**kw)
+
+def model_config(cfg: dict):
+    """The program's ModelConfig from the configuration file; keys that are
+    not its fields are left out."""
+    from repro.models.config import ModelConfig
+
+    return _from_json(ModelConfig, cfg)
+
+
+REFERENCE_EXPORTS = ("init_params", "forward_loss", "forward_flops_per_token")
 
 
 def load_reference(cfg: dict):
+    """The configuration's reference module, which has to export
+    ``REFERENCE_EXPORTS`` (see the module docstring)."""
     path = BENCH / "configs" / cfg["reference"]
     spec = importlib.util.spec_from_file_location(f"bench_ref_{path.stem}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
+    missing = [name for name in REFERENCE_EXPORTS if not callable(getattr(mod, name, None))]
+    if missing:
+        raise AttributeError(f"the reference module {path} exports no {', '.join(missing)}")
     return mod
 
 
@@ -157,6 +199,10 @@ def run(cell, devices) -> Outcome:
     tr = cfg["train"]
     n, B, S = tr["workers"], t["batch_per_worker"], t["seq_len"]
     checked = t["checked_rounds"]
+    ref = load_reference(cfg)
+    flops_per_token = counts.train_flops_per_token(ref.forward_flops_per_token(cfg, S))
+    print(f"bench: {flops_per_token!r} model FLOPs a token, forward and backward, "
+          f"from {cfg['reference']}", file=sys.stderr)
     mcfg = model_config(cfg)
     key = seed_key(cell.seed)
     k_data = jax.random.fold_in(key, 1)
@@ -165,7 +211,6 @@ def run(cell, devices) -> Outcome:
     norms = jax.jit(lambda tree: [jnp.sqrt(jnp.sum(jnp.square(l))) for l in jax.tree.leaves(tree)])
     prog: Dict[str, list] = {"change": []}
     x0 = {}
-    ref = load_reference(cfg)
 
     def read_state(i, state):
         if i == 0:
@@ -206,7 +251,7 @@ def run(cell, devices) -> Outcome:
     if tracewin is not None:
         pk = peaks(devices[0].device_kind)
         work = {"rounds": rounds, "tokens": rounds * tokens_per_round,
-                "flops_per_token": counts.lm_train_flops_per_token(cfg, S),
+                "flops_per_token": flops_per_token,
                 "peak_flops": pk["bf16_flops"]}
         traced = tracewin.reduce(rec.spans, work, len(devices))
 
@@ -225,14 +270,15 @@ def run(cell, devices) -> Outcome:
     prog["loss"] = [rec.losses.get(i, math.nan) for i in range(checked)]
     if "after" in x0:
         server, workers = x0.pop("after")
-        prog["change"] = [ref.leaf_change_norms(server, x0["tree"])] + [
-            ref.leaf_change_norms(w, x0["tree"]) for w in ref.worker_trees(workers, n)]
+        prog["change"] = [reference_train.leaf_change_norms(server, x0["tree"])] + [
+            reference_train.leaf_change_norms(w, x0["tree"])
+            for w in reference_train.worker_trees(workers, n)]
         del server, workers
     x0.clear()
     k_init, k_steps = jax.random.split(key)
     t_ref = time.perf_counter()
-    reference = ref.run(cfg, t, pool[:checked], k_init,
-                        [jax.random.fold_in(k_steps, i) for i in range(checked)])
+    reference = reference_train.run(ref, cfg, t, pool[:checked], k_init,
+                                    [jax.random.fold_in(k_steps, i) for i in range(checked)])
     checks = train_checks(prog, reference, cell.limits)
     print(f"bench: reference took {time.perf_counter() - t_ref} s; losses program "
           f"{prog['loss']} reference {reference['loss']}", file=sys.stderr)
@@ -257,10 +303,11 @@ def control(cell, devices, levels=("fp8",)) -> Dict[str, List[Check]]:
                       cfg["vocab_size"])
     k_init, k_steps = jax.random.split(key)
     keys = [jax.random.fold_in(k_steps, i) for i in range(checked)]
-    exact = ref.run(cfg, t, pool, k_init, keys)
+    exact = reference_train.run(ref, cfg, t, pool, k_init, keys)
     out = {}
     for level in levels:
         fault = level[len("fault:"):] if level.startswith("fault:") else ""
-        low = ref.run(cfg, t, pool, k_init, keys, matmul="f32" if fault else level, fault=fault)
+        low = reference_train.run(ref, cfg, t, pool, k_init, keys,
+                                  matmul="f32" if fault else level, fault=fault)
         out[level] = train_checks(low, exact, cell.limits)
     return out

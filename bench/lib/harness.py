@@ -5,10 +5,18 @@ Everything that belongs to one configuration, traffic mix or per-layer
 metric sits in a file of its own, found by the name in ``BENCHMARK.json``:
 
     bench/configs/<config>.json    the configuration as it is run
+    bench/configs/<model>_reference.py
+                                   its plain reference, named by the
+                                   configuration's ``reference`` key
     bench/traffic/<mix>.json       the mix's parameters; ``driver`` names the
                                    general driver in bench/lib/drivers/
     bench/limits/<workload>.json   the limits of the numbers compared
     bench/metrics/<metric>.py      ``read(traced) -> float | None``
+
+A new configuration of a kind a driver already runs comes in as new files
+and new entries of ``BENCHMARK.json`` only: no file here or in the drivers
+names a model. What a training configuration's files hold is listed in
+``bench/lib/drivers/train.py``.
 """
 from __future__ import annotations
 

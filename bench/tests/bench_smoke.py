@@ -1,6 +1,5 @@
-"""The benchmark's cell at a size a CPU test run can hold: the zamba2
-configuration at smoke widths, its layer pattern, and so the weights'
-layout, kept."""
+"""The benchmark's cells at a size a CPU test run can hold: a configuration
+at smoke widths, its layer pattern, and so the weights' layout, kept."""
 from bench.lib.harness import BENCH, Cell, load_json
 
 SMOKE_WIDTHS = dict(d_model=64, num_heads=4, num_kv_heads=4, head_dim=16, d_ff=128,
@@ -12,12 +11,23 @@ SMOKE_WIDTHS = dict(d_model=64, num_heads=4, num_kv_heads=4, head_dim=16, d_ff=1
 # grad 2.0e-2, grad_diff 5.2e-2, change 1.8e-2); upper, the float8 control's
 # grad_diff (0.32) and the half-batch fault (grad 0.55, change 0.21).
 SMOKE_LIMITS = {"loss_gap": 0.0035, "grad_gap": 0.1, "grad_diff": 0.14, "change_gap": 0.065}
+SMOKE_TRAFFIC = dict(seq_len=64, pool=8)
+
+
+def smoke_cell(config_file: str, widths: dict, limits: dict, workload: str,
+               traffic: str = "marina-step", traffic_sizes: dict = SMOKE_TRAFFIC,
+               seed: int = 2**31 + 17, seconds: float = 0.5, trace: bool = False) -> Cell:
+    """The cell ``workload`` with the configuration ``bench/configs/<config_file>``
+    updated by ``widths`` (a nested group is replaced whole) and the mix
+    ``bench/traffic/<traffic>.json`` by ``traffic_sizes``."""
+    cfg = load_json(BENCH / "configs" / config_file)
+    cfg.update(widths)
+    mix = load_json(BENCH / "traffic" / f"{traffic}.json")
+    mix.update(traffic_sizes)
+    return Cell(workload, cfg, mix, dict(limits), 1, seed, seconds, trace)
 
 
 def zamba2_cell(workload: str = "zamba2-marina-step", seed: int = 2**31 + 17,
                 seconds: float = 0.5, trace: bool = False) -> Cell:
-    cfg = load_json(BENCH / "configs" / "zamba2-1.2b-p1.json")
-    cfg.update(SMOKE_WIDTHS)
-    traffic = load_json(BENCH / "traffic" / "marina-step.json")
-    traffic.update(seq_len=64, pool=8)
-    return Cell(workload, cfg, traffic, dict(SMOKE_LIMITS), 1, seed, seconds, trace)
+    return smoke_cell("zamba2-1.2b-p1.json", SMOKE_WIDTHS, SMOKE_LIMITS, workload,
+                      seed=seed, seconds=seconds, trace=trace)
